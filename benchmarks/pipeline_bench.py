@@ -100,11 +100,12 @@ def _execution_rows():
     from repro.configs.base import get_config
     from repro.core.partition import even_boundaries, partition_layers
     from repro.dist.pipeline import make_pipeline_forward, pad_pipeline_params
+    from repro.launch.mesh import make_mesh
     from repro.models import transformer as tf
 
     stages = min(4, len(jax.devices()))
-    mesh = jax.make_mesh((len(jax.devices()) // stages, stages),
-                         ("data", "model"))
+    mesh = make_mesh((len(jax.devices()) // stages, stages),
+                     ("data", "model"))
     cfg = get_config("qwen3_0p6b").scaled_down(
         num_layers=8, d_model=128, vocab=512
     )

@@ -62,7 +62,6 @@ import numpy as np
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from repro.core.partition import (  # noqa: F401  (bubble oracle re-export)
@@ -390,12 +389,12 @@ def make_pipeline_forward(cfg, mesh: Mesh, num_microbatches: int = 8,
                 return jax.lax.psum(outs, MDL)
 
         io_spec = P(*fix_spec((None, _dp(mesh)), x_mb.shape, mesh))
-        piped = shard_map(
+        piped = jax.shard_map(
             stage_fn,
             mesh=mesh,
             in_specs=(P(MDL), P(), io_spec),
             out_specs=io_spec,
-            check_rep=False,
+            check_vma=False,
         )
         x = piped(params["blocks"], shared, x_mb).reshape(b, s, d)
         return tf._head(params, cfg, x)
@@ -636,12 +635,12 @@ def make_pipeline_loss_and_grad(cfg, mesh: Mesh, num_microbatches: int = 8,
 
         io_spec = P(*io_fixed)
         tgt_spec = P(*fix_spec((None, _dp(mesh)), t_mb.shape, mesh))
-        piped = shard_map(
+        piped = jax.shard_map(
             stage_fn,
             mesh=mesh,
             in_specs=(P(MDL), P(), io_spec, tgt_spec),
             out_specs=(P(MDL), P(), io_spec, P(), P()),
-            check_rep=False,
+            check_vma=False,
         )
         gblocks, ghead, dxq, ce, aux = piped(
             params["blocks"], head_tree, x_mb, t_mb

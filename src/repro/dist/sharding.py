@@ -179,6 +179,33 @@ def hint_dp(x):
     return hint(x, DP)
 
 
+def per_shard(fn, arrays, scalars=()):
+    """``fn(*arrays, *scalars)`` run once per shard of the active mesh.
+
+    Mosaic (Pallas TPU) kernels cannot be partitioned automatically, so
+    under a mesh of more than one device a kernel call goes through
+    ``shard_map``: dim 0 (batch) of every array and of the result splits
+    over the data axes, dim 2 (heads) over 'model', each only where every
+    array divides; ``scalars`` replicate.  With no such mesh, or inside
+    a shard_map body (:func:`manual_mode`), ``fn`` is called directly.
+    """
+    mesh = _current_mesh()
+    if _MANUAL.get() or mesh is None or mesh.size == 1:
+        return fn(*arrays, *scalars)
+    dp = _dp(mesh)
+    batch = dp if all(a.shape[0] % _axis_size(mesh, dp) == 0
+                      for a in arrays) else None
+    heads = MDL if MDL in mesh.shape and all(
+        a.shape[2] % mesh.shape[MDL] == 0 for a in arrays) else None
+    spec = P(batch, None, heads)
+    scalars = tuple(jax.numpy.asarray(x) for x in scalars)
+    return jax.shard_map(
+        fn, mesh=mesh,
+        in_specs=(spec,) * len(arrays) + (P(),) * len(scalars),
+        out_specs=spec, check_vma=False,
+    )(*arrays, *scalars)
+
+
 # ---------------------------------------------------------------------------
 # input / cache specs
 # ---------------------------------------------------------------------------

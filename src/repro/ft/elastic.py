@@ -22,13 +22,12 @@ from jax.sharding import Mesh, NamedSharding
 
 from repro.dist.sharding import param_specs
 from repro.ft import checkpoint as ckpt
+from repro.launch.mesh import make_mesh
 from repro.optim.adamw import OptState
 
 
 def make_mesh_for(devices=None, model_axis: int | None = None) -> Mesh:
     """Form a (data, model) mesh from whatever devices survive."""
-    import numpy as np
-
     devices = list(devices if devices is not None else jax.devices())
     n = len(devices)
     if model_axis is None:
@@ -37,8 +36,8 @@ def make_mesh_for(devices=None, model_axis: int | None = None) -> Mesh:
         while model_axis * 2 <= int(n ** 0.5):
             model_axis *= 2
     data_axis = n // model_axis
-    devs = np.asarray(devices[: data_axis * model_axis]).reshape(data_axis, model_axis)
-    return Mesh(devs, ("data", "model"))
+    return make_mesh((data_axis, model_axis), ("data", "model"),
+                     devices=devices[: data_axis * model_axis])
 
 
 def state_shardings(state_like, mesh: Mesh, strategy: str = "fused"):

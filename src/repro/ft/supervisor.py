@@ -55,11 +55,9 @@ import dataclasses
 import math
 import time
 
-import numpy as np
-
 import jax
 import jax.numpy as jnp
-from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.data.pipeline import SyntheticLM
 from repro.dist.sharding import param_specs
@@ -68,6 +66,7 @@ from repro.ft.elastic import make_mesh_for
 from repro.ft.faults import one_shot_write_fault
 from repro.ft.health import HeartbeatMonitor
 from repro.ft.straggler import StragglerMonitor
+from repro.launch.mesh import make_mesh
 from repro.optim.adamw import AdamWConfig, OptState
 from repro.train.step import (
     init_pipeline_state,
@@ -197,10 +196,8 @@ class TrainSupervisor:
             if self.batch % self.microbatches:
                 raise ValueError(f"batch {self.batch} % microbatches "
                                  f"{self.microbatches} != 0")
-            self.mesh = Mesh(
-                np.asarray(self.devices).reshape(1, self.stages),
-                ("data", "model"),
-            )
+            self.mesh = make_mesh((1, self.stages), ("data", "model"),
+                                  devices=self.devices)
             step_fn = make_pipeline_train_step(
                 cfg, self.opt_cfg, self.mesh,
                 num_microbatches=self.microbatches,

@@ -45,7 +45,6 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels.flash_attention import MASK_VALUE, _pad_axis
-from repro.kernels.vta_gemm import _compiler_params
 
 DEFAULT_BLOCK_K = 512
 
@@ -128,8 +127,32 @@ def _split_kv_partition(
         l_ref[...] = jnp.zeros_like(l_ref)
 
 
+def _partition_outputs(b, hkv, np_, rows, dv, with_counts):
+    """Out specs/shapes of a split-KV kernel with grid (b, hkv, np_):
+    per partition, the (rows, dv) partial output, its (rows, 1) m and l
+    statistics and, with counts, a (1, 1) execution flag.  Every block
+    spans the full extent of the array's last two dims, the TPU tiling
+    rule that a block such as (1, rows) on a (np_, rows) tail breaks."""
+    def spec(*tail):
+        return pl.BlockSpec((1, 1, 1) + tail,
+                            lambda ib, ih, ip, *_: (ib, ih, ip, 0, 0))
+
+    out_specs = [spec(rows, dv), spec(rows, 1), spec(rows, 1)]
+    out_shape = [
+        jax.ShapeDtypeStruct((b, hkv, np_, rows, dv), jnp.float32),
+        jax.ShapeDtypeStruct((b, hkv, np_, rows, 1), jnp.float32),
+        jax.ShapeDtypeStruct((b, hkv, np_, rows, 1), jnp.float32),
+    ]
+    if with_counts:
+        out_specs.append(spec(1, 1))
+        out_shape.append(jax.ShapeDtypeStruct((b, hkv, np_, 1, 1), jnp.int32))
+    return out_specs, out_shape
+
+
 def _combine_partitions(o_part, m_part, l_part):
-    """Cross-partition max / logsumexp merge on (B, Hkv, P, G) arrays."""
+    """Cross-partition max / logsumexp merge: o_part (B, Hkv, P, G, Dv),
+    m_part / l_part (B, Hkv, P, G, 1)."""
+    m_part, l_part = m_part[..., 0], l_part[..., 0]
     m_glob = jnp.max(m_part, axis=2, keepdims=True)
     # dead partitions carry m = -inf; exp(-inf - finite) = 0 kills them
     alpha = jnp.exp(m_part - jnp.maximum(m_glob, MASK_VALUE))
@@ -188,19 +211,8 @@ def decode_attention(
         live_last = jnp.maximum((sref[0] - 1) // kc, 0)
         return ib, ih, jnp.clip(jnp.minimum(ip, live_last), 0, np_ - 1), 0
 
-    out_specs = [
-        pl.BlockSpec((1, 1, 1, g, dv), lambda ib, ih, ip, s: (ib, ih, ip, 0, 0)),
-        pl.BlockSpec((1, 1, 1, g), lambda ib, ih, ip, s: (ib, ih, ip, 0)),
-        pl.BlockSpec((1, 1, 1, g), lambda ib, ih, ip, s: (ib, ih, ip, 0)),
-    ]
-    out_shape = [
-        jax.ShapeDtypeStruct((b, hkv, np_, g, dv), jnp.float32),
-        jax.ShapeDtypeStruct((b, hkv, np_, g), jnp.float32),
-        jax.ShapeDtypeStruct((b, hkv, np_, g), jnp.float32),
-    ]
-    if return_counts:
-        out_specs.append(pl.BlockSpec((1, 1, 1), lambda ib, ih, ip, s: (ib, ih, ip)))
-        out_shape.append(jax.ShapeDtypeStruct((b, hkv, np_), jnp.int32))
+    out_specs, out_shape = _partition_outputs(b, hkv, np_, g, dv,
+                                              return_counts)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
@@ -217,7 +229,7 @@ def decode_attention(
                           with_counts=return_counts),
         grid_spec=grid_spec,
         out_shape=out_shape,
-        compiler_params=_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")
         ),
         interpret=interpret,
@@ -225,7 +237,7 @@ def decode_attention(
     # max / logsumexp combine across partitions (cheap: (B,Hkv,P,G))
     out = _combine_partitions(*res[:3]).reshape(b, 1, h, dv).astype(q.dtype)
     if return_counts:
-        return out, res[3]
+        return out, res[3].reshape(b, hkv, np_)
     return out
 
 
@@ -372,21 +384,8 @@ def paged_decode_attention(
         return ih, jnp.clip(page, 0, num_pages - 1), 0, 0
 
     rows = s * g
-    out_specs = [
-        pl.BlockSpec((1, 1, 1, rows, dv),
-                     lambda ib, ih, ip, *_: (ib, ih, ip, 0, 0)),
-        pl.BlockSpec((1, 1, 1, rows), lambda ib, ih, ip, *_: (ib, ih, ip, 0)),
-        pl.BlockSpec((1, 1, 1, rows), lambda ib, ih, ip, *_: (ib, ih, ip, 0)),
-    ]
-    out_shape = [
-        jax.ShapeDtypeStruct((b, hkv, max_pp, rows, dv), jnp.float32),
-        jax.ShapeDtypeStruct((b, hkv, max_pp, rows), jnp.float32),
-        jax.ShapeDtypeStruct((b, hkv, max_pp, rows), jnp.float32),
-    ]
-    if return_counts:
-        out_specs.append(
-            pl.BlockSpec((1, 1, 1), lambda ib, ih, ip, *_: (ib, ih, ip)))
-        out_shape.append(jax.ShapeDtypeStruct((b, hkv, max_pp), jnp.int32))
+    out_specs, out_shape = _partition_outputs(b, hkv, max_pp, rows, dv,
+                                              return_counts)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=len(scalars),
@@ -405,7 +404,7 @@ def paged_decode_attention(
                           num_pages=num_pages, max_pp=max_pp, qs=s, group=g),
         grid_spec=grid_spec,
         out_shape=out_shape,
-        compiler_params=_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")
         ),
         interpret=interpret,
@@ -413,7 +412,7 @@ def paged_decode_attention(
     out = (_combine_partitions(*res[:3]).reshape(b, hkv, s, g, dv)
            .transpose(0, 2, 1, 3, 4).reshape(b, s, h, dv).astype(q.dtype))
     if return_counts:
-        return out, res[3]
+        return out, res[3].reshape(b, hkv, max_pp)
     return out
 
 

@@ -47,8 +47,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.vta_gemm import _compiler_params
-
 # Finite stand-in for -inf on masked logits: exp(mask - m) underflows to
 # exactly 0 without the exp(-inf - (-inf)) = nan hazard (guide §Numerics).
 MASK_VALUE = -0.7 * float(jnp.finfo(jnp.float32).max)
@@ -197,7 +195,7 @@ def _flash_call(q, k, v, scalars, *, window, bidirectional, scale,
         kernel,
         grid_spec=grid_spec,
         out_shape=out_shape,
-        compiler_params=_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel", "arbitrary")
         ),
         interpret=interpret,

@@ -30,17 +30,13 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# renamed TPUCompilerParams -> CompilerParams across jax releases
-def _compiler_params(**kwargs):
-    cls = getattr(pltpu, "CompilerParams",
-                  getattr(pltpu, "TPUCompilerParams", None))
-    if cls is None:
-        raise AttributeError(
-            "jax.experimental.pallas.tpu exposes neither CompilerParams "
-            "nor TPUCompilerParams; this jax release renamed the pallas "
-            "compiler-params API again"
-        )
-    return cls(**kwargs)
+def _int8_dot(a, w):
+    """int8 operands straight into the MXU, int32 accumulation.  The
+    product is exact, so precision is pinned to DEFAULT: under
+    ``default_matmul_precision("highest")`` Mosaic would otherwise be
+    asked for an fp32 contraction, which it refuses for int8."""
+    return jnp.dot(a, w, precision=jax.lax.Precision.DEFAULT,
+                   preferred_element_type=jnp.int32)
 
 
 def _gemm_kernel(a_ref, w_ref, out_ref, acc_ref, *, n_k: int):
@@ -51,11 +47,7 @@ def _gemm_kernel(a_ref, w_ref, out_ref, acc_ref, *, n_k: int):
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    acc_ref[...] += jnp.dot(
-        a_ref[...].astype(jnp.int32),
-        w_ref[...].astype(jnp.int32),
-        preferred_element_type=jnp.int32,
-    )
+    acc_ref[...] += _int8_dot(a_ref[...], w_ref[...])
 
     @pl.when(k == n_k - 1)
     def _store():
@@ -72,11 +64,7 @@ def _gemm_epilogue_kernel(
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    acc_ref[...] += jnp.dot(
-        a_ref[...].astype(jnp.int32),
-        w_ref[...].astype(jnp.int32),
-        preferred_element_type=jnp.int32,
-    )
+    acc_ref[...] += _int8_dot(a_ref[...], w_ref[...])
 
     @pl.when(k == n_k - 1)
     def _store():
@@ -115,11 +103,7 @@ def _gemm_dequant_kernel(
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    acc_ref[...] += jnp.dot(
-        a_ref[...].astype(jnp.int32),
-        w_ref[...].astype(jnp.int32),
-        preferred_element_type=jnp.int32,
-    )
+    acc_ref[...] += _int8_dot(a_ref[...], w_ref[...])
 
     @pl.when(k == n_k - 1)
     def _store():
@@ -169,7 +153,7 @@ def vta_gemm(
         grid=grid,
         scratch_shapes=[acc],
         interpret=interpret,
-        compiler_params=_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")
         ),
     )

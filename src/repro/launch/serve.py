@@ -23,6 +23,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 from repro.configs.base import get_config
 from repro.dist.sharding import cache_specs, param_specs
 from repro.ft.elastic import make_mesh_for
+from repro.launch.compile_cache import use_compile_cache
 from repro.models import transformer as tf
 from repro.serve.step import make_prefill_step, make_serve_step
 
@@ -109,8 +110,7 @@ def _run_paged_engine(params, cfg, args):
         print(f"  {len(done) - len(finished)} requests cancelled "
               "(deadline/shed)")
     if not finished:
-        print("paged engine: no requests finished")
-        return
+        raise SystemExit("paged engine: no requests finished")
     done = finished
     stats = latency_stats(done)
     print(f"paged engine: {len(done)} requests, {stats['tokens']} tokens "
@@ -215,6 +215,7 @@ def main(argv=None):
                          "cancelled within one supervised step (implies "
                          "--supervise)")
     args = ap.parse_args(argv)
+    use_compile_cache()
 
     cfg = get_config(args.arch)
     if args.smoke:

@@ -15,22 +15,32 @@ import time
 
 import jax
 import jax.numpy as jnp
-from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.configs.base import get_config
 from repro.data.pipeline import Prefetcher, SyntheticLM
-from repro.dist.sharding import data_specs, param_specs
 from repro.ft.checkpoint import AsyncCheckpointer
-from repro.ft.elastic import make_mesh_for
+from repro.ft.elastic import make_mesh_for, state_shardings
 from repro.ft.straggler import StragglerMonitor
+from repro.launch.compile_cache import use_compile_cache
 from repro.launch.mesh import make_production_mesh
-from repro.optim.adamw import AdamWConfig, OptState
+from repro.optim.adamw import AdamWConfig
 from repro.train.step import (
     init_pipeline_state,
     init_state,
     make_pipeline_train_step,
     make_train_step,
 )
+
+
+def jit_train_step(step_fn, state, mesh, strategy: str):
+    """Place ``state`` on ``mesh`` per ``strategy`` and jit ``step_fn``
+    with matching state shardings, the state donated.  Returns
+    (placed state, jitted step, state shardings)."""
+    sshard = state_shardings(state, mesh, strategy)
+    state = jax.tree.map(jax.device_put, state, sshard)
+    jitted = jax.jit(step_fn, in_shardings=(sshard, None),
+                     out_shardings=(sshard, None), donate_argnums=(0,))
+    return state, jitted, sshard
 
 
 def main(argv=None):
@@ -66,6 +76,7 @@ def main(argv=None):
                     help="run the measured-cost kernel knob search "
                          "(core.autotune.tune_runtime) before training")
     args = ap.parse_args(argv)
+    use_compile_cache()
 
     cfg = get_config(args.arch)
     if args.smoke:
@@ -147,15 +158,8 @@ def main(argv=None):
             )
         else:
             state = init_state(jax.random.PRNGKey(0), cfg, jnp.float32)
-        pspecs = param_specs(state["params"], mesh, args.strategy)
-        sspecs = {"params": pspecs,
-                  "opt": OptState(mu=pspecs, nu=pspecs, step=P()),
-                  "step": P()}
-        sshard = jax.tree.map(lambda s: NamedSharding(mesh, s), sspecs,
-                              is_leaf=lambda x: isinstance(x, P))
-        state = jax.tree.map(jax.device_put, state, sshard)
-        jitted = jax.jit(step_fn, in_shardings=(sshard, None),
-                         out_shardings=(sshard, None), donate_argnums=(0,))
+        state, jitted, sshard = jit_train_step(step_fn, state, mesh,
+                                               args.strategy)
 
         ckpt = AsyncCheckpointer(args.ckpt, keep=2) if args.ckpt else None
         start = 0

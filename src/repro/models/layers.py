@@ -354,15 +354,20 @@ def flash_attend(
         block_q = block_q if block_q is not None else t.get("block_q")
         block_k = block_k if block_k is not None else t.get("block_k")
     if _pallas_attention():
+        from repro.dist.sharding import per_shard
         from repro.kernels.flash_attention import flash_attention
 
-        return flash_attention(
-            q, k, v, q_offset=q_offset, window=window,
-            bidirectional=bidirectional, scale=scale, kv_len=kv_len,
-            block_q=int(block_q) if block_q else min(q_chunk, 128),
-            block_k=int(block_k) if block_k else min(kv_chunk, 128),
-            interpret=_pallas_interpret(),
-        )
+        def kernel(q, k, v, q_offset, kv_len):
+            return flash_attention(
+                q, k, v, q_offset=q_offset, window=window,
+                bidirectional=bidirectional, scale=scale, kv_len=kv_len,
+                block_q=int(block_q) if block_q else min(q_chunk, 128),
+                block_k=int(block_k) if block_k else min(kv_chunk, 128),
+                interpret=_pallas_interpret(),
+            )
+
+        return per_shard(kernel, (q, k, v),
+                         (q_offset, k.shape[1] if kv_len is None else kv_len))
     return flash_attend_ref(
         q, k, v, q_offset=q_offset, window=window,
         bidirectional=bidirectional, scale=scale,

@@ -158,9 +158,10 @@ os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
 import jax, jax.numpy as jnp, numpy as np
 from repro.configs.base import get_config
 from repro.dist.pipeline import make_pipeline_forward
+from repro.launch.mesh import make_mesh
 from repro.models import transformer as tf
 cfg = get_config("qwen3_0p6b").scaled_down(num_layers=4, d_model=64, vocab=256)
-mesh = jax.make_mesh((2, 2), ("data", "model"))
+mesh = make_mesh((2, 2), ("data", "model"))
 params = tf.init(jax.random.PRNGKey(0), cfg, jnp.float32)
 tokens = jax.random.randint(jax.random.PRNGKey(1), (8, 16), 0, cfg.vocab)
 want, _ = tf.forward(params, cfg, tokens)
@@ -189,13 +190,14 @@ from repro.core.placement import to_placement
 from repro.core.scheduler import rebalance
 from repro.core.strategies import make_plan
 from repro.dist.pipeline import make_pipeline_forward, pad_pipeline_params
+from repro.launch.mesh import make_mesh
 from repro.models import transformer as tf
 
 cfg = get_config("qwen3_0p6b").scaled_down(num_layers=8, d_model=64, vocab=256)
 g = config_graph(cfg, seq_len=16)
 plan = rebalance(g, make_plan(g, "pipeline", 4),
                  {0: 0.25, 1: 1.0, 2: 1.0, 3: 1.0})  # stage 0 straggles
-mesh = jax.make_mesh((1, 4), ("data", "model"))
+mesh = make_mesh((1, 4), ("data", "model"))
 placement = to_placement(plan, mesh, num_microbatches=4, graph=g)
 depths = np.diff(placement.layer_boundaries)
 assert depths[0] < depths.max(), placement.layer_boundaries  # uneven cut
@@ -231,11 +233,12 @@ os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
 import jax, jax.numpy as jnp, numpy as np
 from repro.configs.base import get_config
 from repro.dist.pipeline import make_pipeline_loss_and_grad, pad_pipeline_params
+from repro.launch.mesh import make_mesh
 from repro.models import transformer as tf
 from repro.train.step import make_loss_fn
 
 cfg = get_config("qwen3_0p6b").scaled_down(num_layers=4, d_model=64, vocab=256)
-mesh = jax.make_mesh((2, 2), ("data", "model"))
+mesh = make_mesh((2, 2), ("data", "model"))
 bounds = (0, 1, 4)  # uneven: stage 0 one layer, stage 1 three
 params = tf.init(jax.random.PRNGKey(0), cfg, jnp.float32)
 padded = pad_pipeline_params(params, cfg, bounds)
@@ -269,7 +272,7 @@ print("TRAIN_MATCH_OK")
 # regression: microbatch dim NOT divisible by the data axes (fix_spec
 # drops them, x_mb replicates) — the dX normalizer must follow the
 # EFFECTIVE shard count or embedding grads come out scaled by 1/ndp
-mesh4 = jax.make_mesh((4, 1), ("data", "model"))
+mesh4 = make_mesh((4, 1), ("data", "model"))
 b4 = {"tokens": batch["tokens"][:4]}
 with mesh4:
     lg4 = make_pipeline_loss_and_grad(cfg, mesh4, num_microbatches=4)
@@ -296,13 +299,14 @@ os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
 import jax, jax.numpy as jnp, numpy as np
 from repro.configs.base import get_config
 from repro.dist.pipeline import make_pipeline_forward, pad_pipeline_params
+from repro.launch.mesh import make_mesh
 from repro.models import transformer as tf
 
 # capacity_factor = experts/top_k makes the global cap provably
 # dropless, so the full-batch run is below capacity by construction
 mcfg = get_config("mixtral_8x22b").scaled_down(
     num_layers=4, d_model=64, vocab=256, moe_capacity_factor=2.0)
-mesh = jax.make_mesh((1, 4), ("data", "model"))
+mesh = make_mesh((1, 4), ("data", "model"))
 params = tf.init(jax.random.PRNGKey(0), mcfg, jnp.float32)
 tokens = jax.random.randint(jax.random.PRNGKey(1), (8, 16), 0, mcfg.vocab)
 want, _ = tf.forward(params, mcfg, tokens)
@@ -321,7 +325,7 @@ hcfg = get_config("zamba2_2p7b").scaled_down(num_layers=8, attn_every=2,
 hparams = tf.init(jax.random.PRNGKey(0), hcfg, jnp.float32)
 htok = jax.random.randint(jax.random.PRNGKey(1), (4, 16), 0, hcfg.vocab)
 hwant, _ = tf.forward(hparams, hcfg, htok)
-mesh2 = jax.make_mesh((2, 2), ("data", "model"))
+mesh2 = make_mesh((2, 2), ("data", "model"))
 hb = (0, 1, 4)  # uneven GROUP cuts: 1 group vs 3 groups
 hp = pad_pipeline_params(hparams, hcfg, hb)
 with mesh2:
@@ -331,6 +335,36 @@ np.testing.assert_allclose(np.asarray(hgot), np.asarray(hwant), atol=2e-4, rtol=
 print("HYBRID_OK")
 """
         _run_pipeline_subprocess(code, "HYBRID_OK")
+
+
+    def test_flash_kernel_per_shard_matches_jnp(self):
+        """Under a 2x2 mesh the Pallas flash kernel runs per shard
+        (shard_map over batch and heads), forward and backward, and
+        matches the jnp reference."""
+        code = r"""
+import os
+os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
+import jax, jax.numpy as jnp, numpy as np
+from repro.launch.mesh import make_mesh
+from repro.models import layers
+ks = jax.random.split(jax.random.PRNGKey(0), 3)
+q = jax.random.normal(ks[0], (4, 64, 4, 32))
+k = jax.random.normal(ks[1], (4, 64, 2, 32))
+v = jax.random.normal(ks[2], (4, 64, 2, 32))
+
+def loss(q, k, v):
+    return jnp.sum(layers.flash_attend(q, k, v, block_q=32, block_k=32) ** 2)
+
+want = jax.value_and_grad(loss, argnums=(0, 1, 2))(q, k, v)
+layers.set_attention_impl("pallas")
+with make_mesh((2, 2), ("data", "model")):
+    got = jax.jit(jax.value_and_grad(loss, argnums=(0, 1, 2)))(q, k, v)
+    assert "shard_map" in str(jax.make_jaxpr(loss)(q, k, v))
+for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+    np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=1e-4, rtol=1e-4)
+print("PER_SHARD_OK")
+"""
+        _run_pipeline_subprocess(code, "PER_SHARD_OK")
 
 
 class TestPlacement:
