@@ -228,6 +228,7 @@ def decode_attention(
         functools.partial(_decode_kernel, kc=kc, window=window, scale=scale,
                           with_counts=return_counts),
         grid_spec=grid_spec,
+        name="split_kv_decode_attention",
         out_shape=out_shape,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")
@@ -403,6 +404,7 @@ def paged_decode_attention(
                           with_counts=return_counts, quantized=quantized,
                           num_pages=num_pages, max_pp=max_pp, qs=s, group=g),
         grid_spec=grid_spec,
+        name="paged_decode_attention",
         out_shape=out_shape,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")

@@ -194,6 +194,7 @@ def _flash_call(q, k, v, scalars, *, window, bidirectional, scale,
     out = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
+        name="flash_attention",
         out_shape=out_shape,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel", "arbitrary")

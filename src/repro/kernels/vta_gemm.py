@@ -161,6 +161,7 @@ def vta_gemm(
     if epilogue == "none":
         return pl.pallas_call(
             functools.partial(_gemm_kernel, n_k=n_k),
+            name="vta_gemm",
             out_shape=jax.ShapeDtypeStruct((m, n), jnp.int32),
             in_specs=[a_spec, w_spec],
             out_specs=out_spec,
@@ -172,6 +173,7 @@ def vta_gemm(
         bias_spec = pl.BlockSpec((1, block_n), lambda i, j, kk: (0, j))
         return pl.pallas_call(
             functools.partial(_gemm_epilogue_kernel, n_k=n_k, shift=shift, relu=relu),
+            name="vta_gemm_requant",
             out_shape=jax.ShapeDtypeStruct((m, n), jnp.int8),
             in_specs=[a_spec, w_spec, bias_spec],
             out_specs=out_spec,
@@ -190,6 +192,7 @@ def vta_gemm(
         return pl.pallas_call(
             functools.partial(_gemm_dequant_kernel, n_k=n_k, act=act,
                               with_bias=bias is not None),
+            name="vta_gemm_dequant",
             out_shape=jax.ShapeDtypeStruct((m, n), jnp.float32),
             in_specs=in_specs,
             out_specs=out_spec,

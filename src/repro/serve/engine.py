@@ -93,6 +93,14 @@ from repro.serve.step import (
 # the serving supervisor's hang recovery) can install a fake clock.
 _now = time.monotonic
 
+# host spans on the profiler's own clock, so a trace puts every idle gap
+# of the device down to the phase of the step the host was in.  With no
+# profiler running a span costs about a microsecond.  Names ending in
+# ``.wait`` mark the host blocked on the device.  Module-level, like
+# ``_now``, so tests can record them.
+_span = jax.profiler.TraceAnnotation
+_step_span = jax.profiler.StepTraceAnnotation
+
 # jitted steps are shared ACROSS engine instances: benchmarks and tests
 # routinely build one engine to warm the compile caches and a second
 # (same cfg) to measure — per-instance jax.jit wrappers would silently
@@ -540,67 +548,19 @@ class ServingEngine:
         sees the earlier admission's prefix)."""
         produced = 0
         while self._queue:
-            now = _now()
-            self._queue.sort(
-                key=lambda r: (-self._eff_priority(r, now), r.rid))
-            req = self._queue[0]
-            # p99-targeted deferral: even one chunk of prefill would
-            # push the in-flight decoders past the SLO this step
-            if (self.slo_s is not None and allowance == 0
-                    and any(s.decoding for s in self.slots)):
-                self._deferred_steps += 1
-                break
-            slot_id = self._free_slot()
+            with _span("engine.admit") as span:
+                slot_id = self._admit_one(allowance)
+                if slot_id is not None:
+                    slot = self.slots[slot_id]
+                    span.set_metadata(rid=slot.req.rid, slot=slot_id,
+                                      pages=len(slot.pages))
             if slot_id is None:
-                victim = self._pick_victim(req, now)
-                if victim is None:
-                    break
-                self._preempt(victim)
-                slot_id = victim
-            need = self._pages_for_request(req)
-            seq = req.seq
-            m, shared = 0, []
-            if self.prefix is not None:
-                # cap the hit at n-1: at least one suffix token must run
-                # through prefill to produce the first output logits
-                # (an int8 tree additionally rounds the hit down to a
-                # page boundary — see RadixPrefixCache.full_pages_only)
-                m, shared = self.prefix.lookup(seq[:-1])
-            fork = m % self.page_size != 0
-            fresh_n = need - len(shared) + (1 if fork else 0)
-            while not self.allocator.can_alloc(fresh_n):
-                if self.prefix is not None:
-                    self.prefix.evict(fresh_n - self.allocator.num_free)
-                    if self.allocator.can_alloc(fresh_n):
-                        break
-                victim = self._pick_victim(req, now)
-                if victim is None:
-                    break
-                self._preempt(victim)
-            if not self.allocator.can_alloc(fresh_n):
-                if self.prefix is not None:
-                    self.allocator.release(shared)
-                break  # keep head-of-queue blocking: no skipping
-            fresh = self.allocator.alloc(fresh_n)
-            if fork:
-                # the shared tail page is partially filled: this slot
-                # will write into it, so copy-on-write it into a fresh
-                # page and drop our reference to the shared original
-                self.blocks = self._fork(self.blocks,
-                                         jnp.int32(shared[-1]),
-                                         jnp.int32(fresh[0]))
-                self.allocator.release([shared[-1]])
-                pages = shared[:-1] + fresh
-            else:
-                pages = shared + fresh
-            self._queue.remove(req)
-            self._assign(slot_id, req, pages, m, now)
+                break
             if self.prefill_budget is None:
                 # admission-stall discipline: run this prefill to
                 # completion before looking at the next request (the
                 # completion-time prefix insert is then visible to the
                 # rest of the wave, preserving same-wave sharing)
-                slot = self.slots[slot_id]
                 t0, chunks = _now(), 0
                 while slot.prefilling:
                     self._advance_slot(slot_id, slot)
@@ -609,6 +569,66 @@ class ServingEngine:
                 self._note_cost("_chunk_ewma",
                                 (_now() - t0) / chunks)
         return produced
+
+    def _admit_one(self, allowance: int | None) -> int | None:
+        """Admit the head of the effective-priority order into a slot
+        (preempting, evicting and allocating pages as needed); returns
+        the slot, or None when the head cannot be admitted now."""
+        now = _now()
+        self._queue.sort(key=lambda r: (-self._eff_priority(r, now), r.rid))
+        req = self._queue[0]
+        # p99-targeted deferral: even one chunk of prefill would
+        # push the in-flight decoders past the SLO this step
+        if (self.slo_s is not None and allowance == 0
+                and any(s.decoding for s in self.slots)):
+            self._deferred_steps += 1
+            return None
+        slot_id = self._free_slot()
+        if slot_id is None:
+            victim = self._pick_victim(req, now)
+            if victim is None:
+                return None
+            self._preempt(victim)
+            slot_id = victim
+        need = self._pages_for_request(req)
+        seq = req.seq
+        m, shared = 0, []
+        if self.prefix is not None:
+            # cap the hit at n-1: at least one suffix token must run
+            # through prefill to produce the first output logits
+            # (an int8 tree additionally rounds the hit down to a
+            # page boundary — see RadixPrefixCache.full_pages_only)
+            m, shared = self.prefix.lookup(seq[:-1])
+        fork = m % self.page_size != 0
+        fresh_n = need - len(shared) + (1 if fork else 0)
+        while not self.allocator.can_alloc(fresh_n):
+            if self.prefix is not None:
+                self.prefix.evict(fresh_n - self.allocator.num_free)
+                if self.allocator.can_alloc(fresh_n):
+                    break
+            victim = self._pick_victim(req, now)
+            if victim is None:
+                break
+            self._preempt(victim)
+        if not self.allocator.can_alloc(fresh_n):
+            if self.prefix is not None:
+                self.allocator.release(shared)
+            return None  # keep head-of-queue blocking: no skipping
+        fresh = self.allocator.alloc(fresh_n)
+        if fork:
+            # the shared tail page is partially filled: this slot
+            # will write into it, so copy-on-write it into a fresh
+            # page and drop our reference to the shared original
+            self.blocks = self._fork(self.blocks,
+                                     jnp.int32(shared[-1]),
+                                     jnp.int32(fresh[0]))
+            self.allocator.release([shared[-1]])
+            pages = shared[:-1] + fresh
+        else:
+            pages = shared + fresh
+        self._queue.remove(req)
+        self._assign(slot_id, req, pages, m, now)
+        return slot_id
 
     def _assign(self, slot_id: int, req: Request, pages: list, m: int,
                 now: float) -> None:
@@ -651,19 +671,22 @@ class ServingEngine:
         cache bucket).  Returns prompt tokens consumed; the slot
         transitions to DECODING when the last chunk lands."""
         seq, n = slot.seq, len(slot.seq)
-        if not self._dyn_prefill:  # SWA: single exact pass
-            tok, slot.dense = self._prefill(self.params,
-                                            jnp.asarray(seq)[None],
-                                            slot.dense)
-            slot.pf_pos, k = n, n
-        else:
-            k = min(self._prefill_chunk, n - slot.pf_pos)
-            piece = np.zeros((1, self._prefill_chunk), np.int32)
-            piece[0, :k] = seq[slot.pf_pos:slot.pf_pos + k]
-            tok, slot.dense = self._prefill(self.params, jnp.asarray(piece),
-                                            slot.dense,
-                                            n_tokens=jnp.int32(k))
-            slot.pf_pos += k
+        # SWA prefills the whole prompt in one exact pass
+        k = (min(self._prefill_chunk, n - slot.pf_pos) if self._dyn_prefill
+             else n)
+        with _span("engine.prefill_chunk", rid=slot.req.rid, n_tokens=k):
+            if not self._dyn_prefill:
+                tok, slot.dense = self._prefill(self.params,
+                                                jnp.asarray(seq)[None],
+                                                slot.dense)
+            else:
+                piece = np.zeros((1, self._prefill_chunk), np.int32)
+                piece[0, :k] = seq[slot.pf_pos:slot.pf_pos + k]
+                tok, slot.dense = self._prefill(self.params,
+                                                jnp.asarray(piece),
+                                                slot.dense,
+                                                n_tokens=jnp.int32(k))
+        slot.pf_pos += k
         self._prefill_chunk_calls += 1
         if slot.pf_pos >= n:
             self._finish_prefill(slot_id, slot, tok)
@@ -673,7 +696,26 @@ class ServingEngine:
         """Last chunk landed: scatter the dense rows into the slot's
         pages, publish the block-table row, emit the first token, and
         flip the slot to DECODING."""
-        req, seq, m, pages = slot.req, slot.seq, slot.n_prefix, slot.pages
+        req = slot.req
+        with _span("engine.page_scatter", rid=req.rid):
+            self._scatter_prefill(slot_id, slot)
+        with _span("engine.prefill.wait", rid=req.rid):
+            first = int(tok[0])
+        # stamped once the token has reached the host
+        now = _now()
+        if req.t_first is None:
+            req.t_first = now
+        req.tokens.append(first)
+        req.token_times.append(now)
+        slot.length = len(slot.seq)
+        if self.eos_id is not None and first == self.eos_id:
+            req.max_new = len(req.tokens)  # eos at prefill: done already
+
+    def _scatter_prefill(self, slot_id: int, slot: _Slot) -> None:
+        """Publish the slot's block-table row and dispatch the copy of
+        its dense prefill rows into its pages (and the draft's prefill);
+        index the sequence in the prefix cache."""
+        seq, m, pages = slot.seq, slot.n_prefix, slot.pages
         n = len(seq)
         self.block_tables[slot_id, :] = -1
         self.block_tables[slot_id, :len(pages)] = pages
@@ -712,14 +754,6 @@ class ServingEngine:
             # index the sequence now that its rows are physically in
             # the pages (an in-flight prefill must never be served)
             self.prefix.insert(seq, pages)
-        now = _now()
-        if req.t_first is None:
-            req.t_first = now
-        req.tokens.append(int(tok[0]))
-        req.token_times.append(now)
-        slot.length = n
-        if self.eos_id is not None and req.tokens[-1] == self.eos_id:
-            req.max_new = len(req.tokens)  # eos at prefill: done already
 
     def _advance_prefills(self, allowance: int | None) -> int:
         """Spend this step's prefill allowance advancing PREFILLING
@@ -751,10 +785,11 @@ class ServingEngine:
                 # a still-prefilling slot's dense cache is the freshest
                 # dispatched work; if every prefill finished this step,
                 # its rows were scattered into the shared pools instead
-                live = next((s.dense for s in self.slots if s.prefilling),
-                            None)
-                tail = live if live is not None else self.blocks
-                jax.block_until_ready(jax.tree_util.tree_leaves(tail)[0])
+                live = next((s for s in self.slots if s.prefilling), None)
+                tail = live.dense if live is not None else self.blocks
+                rid = {"rid": live.req.rid} if live is not None else {}
+                with _span("engine.prefill.wait", probe=1, **rid):
+                    jax.block_until_ready(jax.tree_util.tree_leaves(tail)[0])
                 self._note_cost("_chunk_ewma",
                                 (_now() - t0) / chunks)
         return produced
@@ -871,57 +906,76 @@ class ServingEngine:
         return produced
 
     def _step_inner(self) -> int:
-        # retire-before-admit: a request whose LAST token came from the
-        # previous step (or from prefill, max_new == 1) frees its pages
-        # for this step's admissions
-        for sid, slot in enumerate(self.slots):
-            if slot.decoding and slot.req.done:
-                self._retire(sid, slot)
-        now = _now()
-        allowance = self._prefill_allowance(now)
-        produced = self._admit(allowance)
-        produced += self._advance_prefills(allowance)
-        # max_new == 1 requests finish at prefill: retire before the
-        # decode so they don't produce an extra token
-        for sid, slot in enumerate(self.slots):
-            if slot.decoding and slot.req.done:
-                self._retire(sid, slot)
-        if not any(s.decoding for s in self.slots):
-            return produced
-        if self.spec_k:
-            produced += self._spec_step()
-            self.steps += 1
-            return produced
+        with _step_span("engine.step", step_num=self.steps):
+            # retire-before-admit: a request whose LAST token came from
+            # the previous step (or from prefill, max_new == 1) frees its
+            # pages for this step's admissions
+            self._retire_done()
+            allowance = self._prefill_allowance(_now())
+            produced = self._admit(allowance)
+            produced += self._advance_prefills(allowance)
+            # max_new == 1 requests finish at prefill: retire before the
+            # decode so they don't produce an extra token
+            self._retire_done()
+            if not any(s.decoding for s in self.slots):
+                return produced
+            if self.spec_k:
+                produced += self._spec_step()
+                self.steps += 1
+                return produced
+            return produced + self._decode_step()
 
-        t_dec = _now()
+    def _retire_done(self) -> None:
+        with _span("engine.retire"):
+            for sid, slot in enumerate(self.slots):
+                if slot.decoding and slot.req.done:
+                    self._retire(sid, slot)
+
+    def _decode_inputs(self):
+        """Each decoding slot's last token and length, (B, 1) and (B,),
+        zero for the others."""
         last = np.zeros((self.max_slots, 1), np.int32)
         for sid, slot in enumerate(self.slots):
             if slot.decoding:
                 last[sid, 0] = slot.req.tokens[-1]
-        caches = {
-            "blocks": self.blocks,
-            "block_tables": jnp.asarray(self.block_tables),
-            "lens": jnp.asarray(np.array(
-                [s.length if s.decoding else 0 for s in self.slots],
-                np.int32)),
-        }
-        tok, caches = self._decode(self.params, jnp.asarray(last), caches)
-        self.blocks = caches["blocks"]
+        lens = np.array([s.length if s.decoding else 0 for s in self.slots],
+                        np.int32)
+        return last, lens
+
+    def _decode_step(self) -> int:
+        """One batched paged decode over the DECODING slots; returns
+        the tokens emitted."""
+        t_dec = _now()
+        with _span("engine.decode.prepare"):
+            last, lens = self._decode_inputs()
+            last = jnp.asarray(last)
+            caches = {
+                "blocks": self.blocks,
+                "block_tables": jnp.asarray(self.block_tables),
+                "lens": jnp.asarray(lens),
+            }
+        n_dec = sum(s.decoding for s in self.slots)
+        with _span("engine.decode.dispatch", slots=n_dec):
+            tok, caches = self._decode(self.params, last, caches)
+            self.blocks = caches["blocks"]
         self.steps += 1
-        tok = np.asarray(tok)  # blocks: the step streams its tokens
+        with _span("engine.decode.wait"):
+            tok = np.asarray(tok)  # blocks: the step streams its tokens
         self._note_cost("_decode_ewma", _now() - t_dec)
         now = _now()
-        for sid, slot in enumerate(self.slots):
-            if not slot.decoding:
-                continue
-            req = slot.req
-            slot.length += 1
-            t = int(tok[sid, 0])
-            req.tokens.append(t)
-            req.token_times.append(now)
-            produced += 1
-            if self.eos_id is not None and t == self.eos_id:
-                req.max_new = len(req.tokens)  # truncate: eos ends it
+        produced = 0
+        with _span("engine.emit"):
+            for sid, slot in enumerate(self.slots):
+                if not slot.decoding:
+                    continue
+                req = slot.req
+                slot.length += 1
+                t = int(tok[sid, 0])
+                req.tokens.append(t)
+                req.token_times.append(now)
+                produced += 1
+                if self.eos_id is not None and t == self.eos_id:
+                    req.max_new = len(req.tokens)  # truncate: eos ends it
         return produced
 
     def _spec_step(self) -> int:
@@ -942,68 +996,78 @@ class ServingEngine:
         """
         k = self.spec_k
         t_dec = _now()
-        last = np.zeros((self.max_slots, 1), np.int32)
-        for sid, slot in enumerate(self.slots):
-            if slot.decoding:
-                last[sid, 0] = slot.req.tokens[-1]
-        lens = np.array([s.length if s.decoding else 0 for s in self.slots],
-                        np.int32)
-        # draft chain: k+1 sequential single-token steps — outputs
-        # 0..k-1 are the proposals, the extra step writes the LAST
-        # proposal's KV row so the draft cache stays in lockstep with
-        # the target after a full acceptance
-        dcaches = {
-            "blocks": self.draft_blocks,
-            "block_tables": jnp.asarray(self._draft_bt),
-            "lens": jnp.asarray(lens),
-        }
-        tok, chain = jnp.asarray(last), []
-        for _ in range(k + 1):
-            tok, dcaches = self._draft_decode(self.draft_params, tok,
-                                              dcaches)
-            chain.append(tok)
-        self.draft_blocks = dcaches["blocks"]
-        props = np.asarray(jnp.concatenate(chain[:k], axis=1))  # (B, k)
-        caches = {
-            "blocks": self.blocks,
-            "block_tables": jnp.asarray(self.block_tables),
-            "lens": jnp.asarray(lens),
-        }
-        verify_in = np.concatenate([last, props], axis=1)  # (B, k+1)
-        greedy, caches = self._verify(self.params, jnp.asarray(verify_in),
-                                      caches)
-        self.blocks = caches["blocks"]
-        greedy = np.asarray(greedy)
+        n_dec = sum(s.decoding for s in self.slots)
+        with _span("engine.decode.prepare"):
+            last, lens = self._decode_inputs()
+            # draft chain: k+1 sequential single-token steps — outputs
+            # 0..k-1 are the proposals, the extra step writes the LAST
+            # proposal's KV row so the draft cache stays in lockstep
+            # with the target after a full acceptance
+            dcaches = {
+                "blocks": self.draft_blocks,
+                "block_tables": jnp.asarray(self._draft_bt),
+                "lens": jnp.asarray(lens),
+            }
+            # every step donates its caches: the target has its own lens
+            target = {"block_tables": jnp.asarray(self.block_tables),
+                      "lens": jnp.asarray(lens)}
+            tok, chain = jnp.asarray(last), []
+        with _span("engine.decode.dispatch", slots=n_dec):
+            for _ in range(k + 1):
+                tok, dcaches = self._draft_decode(self.draft_params, tok,
+                                                  dcaches)
+                chain.append(tok)
+            self.draft_blocks = dcaches["blocks"]
+        with _span("engine.decode.wait"):
+            props = np.asarray(jnp.concatenate(chain[:k], axis=1))  # (B, k)
+        with _span("engine.decode.dispatch", slots=n_dec):
+            verify_in = np.concatenate([last, props], axis=1)  # (B, k+1)
+            greedy, caches = self._verify(self.params,
+                                          jnp.asarray(verify_in),
+                                          dict(target, blocks=self.blocks))
+            self.blocks = caches["blocks"]
+        with _span("engine.decode.wait"):
+            greedy = np.asarray(greedy)
         self._note_cost("_decode_ewma", _now() - t_dec)
         now = _now()
         produced = 0
         self._spec_steps += 1
-        for sid, slot in enumerate(self.slots):
-            if not slot.decoding:
-                continue
-            req = slot.req
-            self._spec_slot_steps += 1
-            a = 0
-            while a < k and props[sid, a] == greedy[sid, a]:
-                a += 1
-            appended = 0
-            for j in range(a + 1):
-                if req.done:
-                    break
-                t = int(greedy[sid, j])
-                req.tokens.append(t)
-                req.token_times.append(now)
-                appended += 1
-                if self.eos_id is not None and t == self.eos_id:
-                    req.max_new = len(req.tokens)  # truncate: eos ends it
-                    break
-            # advance by what was actually APPENDED (eos / max_new can
-            # truncate below a+1) — keeps length == n + len(tokens) - 1,
-            # the invariant every later step and retire-insert relies on
-            slot.length += appended
-            produced += appended
-            self._spec_emitted += appended
+        with _span("engine.emit"):
+            for sid, slot in enumerate(self.slots):
+                if not slot.decoding:
+                    continue
+                appended = self._accept(slot.req, props[sid], greedy[sid],
+                                        now)
+                self._spec_slot_steps += 1
+                # advance by what was actually APPENDED (eos / max_new
+                # can truncate below a+1) — keeps length == n +
+                # len(tokens) - 1, the invariant every later step and
+                # retire-insert relies on
+                slot.length += appended
+                produced += appended
+                self._spec_emitted += appended
         return produced
+
+    def _accept(self, req: Request, props, greedy, now: float) -> int:
+        """Append the longest prefix of the draft's proposals that the
+        target agrees with, plus the target's own next token, stopping
+        at end-of-sequence or ``max_new``; returns the tokens appended."""
+        k = self.spec_k
+        a = 0
+        while a < k and props[a] == greedy[a]:
+            a += 1
+        appended = 0
+        for j in range(a + 1):
+            if req.done:
+                break
+            t = int(greedy[j])
+            req.tokens.append(t)
+            req.token_times.append(now)
+            appended += 1
+            if self.eos_id is not None and t == self.eos_id:
+                req.max_new = len(req.tokens)  # truncate: eos ends it
+                break
+        return appended
 
     def run(self, max_steps: int = 100_000) -> list[Request]:
         """Drive steps until every submitted request has retired."""
