@@ -174,10 +174,12 @@ def gqa_apply(p, cfg, x, positions, cache=None, *, bidirectional=False):
             new_cache = {"k_pages": kp, "v_pages": vp,
                          "k_scales": ksc, "v_scales": vsc}
         else:
-            kp = cache["k_pages"].at[:, page, slot].set(
-                k.transpose(2, 0, 1, 3), mode="drop")
-            vp = cache["v_pages"].at[:, page, slot].set(
-                v.transpose(2, 0, 1, 3), mode="drop")
+            from repro.serve.kv_cache import pool_write_rows
+
+            kp = pool_write_rows(cache["k_pages"], k.transpose(2, 0, 1, 3),
+                                 page, slot)
+            vp = pool_write_rows(cache["v_pages"], v.transpose(2, 0, 1, 3),
+                                 page, slot)
             out = paged_decode_attend(q, kp, vp, cache["block_tables"],
                                       new_len, window=cfg.sliding_window)
             new_cache = {"k_pages": kp, "v_pages": vp}

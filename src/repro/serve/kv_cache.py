@@ -711,6 +711,33 @@ def seed_prefix_dense(dense_caches, paged_blocks, block_row, n_prefix):
 # ---------------------------------------------------------------------------
 
 
+def pool_write_rows(pool, rows, page, slot):
+    """Write KV rows into a float ``(Hkv, num_pages, page_size, D)`` pool.
+
+    rows: ``(Hkv, *idx, D)``; page, slot: ``idx``-shaped int32 write
+    coordinates, slot in ``[0, page_size)``.  A row whose page lies
+    outside ``[0, num_pages)`` (``num_pages`` marks an inactive slot, a
+    pad row or a position past the block table) is dropped.
+
+    The rows are scattered into the flat ``(Hkv*num_pages*page_size, D)``
+    view of the pool, a free reshape of its row-major layout, so the
+    pool stays where it is.  The 4-D ``pool.at[:, page, slot]`` scatter
+    makes XLA on TPU relayout the whole pool for the scatter and back
+    again, two pool-sized copies per call.  In the flat view an
+    out-of-range page would land inside the next head's rows, so dropped
+    rows are sent past the end explicitly (all to the same index: no
+    promise of unique indices).  Pure; jit with the pool donated.
+    """
+    hkv, num_pages, pg, d = pool.shape
+    n_rows = hkv * num_pages * pg
+    head = jnp.arange(hkv).reshape((hkv,) + (1,) * page.ndim)
+    idx = (head * num_pages + page) * pg + slot
+    idx = jnp.where((page >= 0) & (page < num_pages), idx, n_rows)
+    flat = pool.reshape(n_rows, d).at[idx.reshape(-1)].set(
+        rows.reshape(-1, d).astype(pool.dtype), mode="drop")
+    return flat.reshape(pool.shape)
+
+
 def write_prompt_pages(paged_blocks, dense_blocks, block_row, n_tokens,
                        row0_pos=0, row_lo=0):
     """Scatter one request's dense-prefill cache rows into its pages.
@@ -802,10 +829,12 @@ def write_prompt_pages(paged_blocks, dense_blocks, block_row, n_tokens,
             })
         else:
             out.append({
-                "k_pages": pool["k_pages"].at[:, page, slot].set(
-                    dense_blocks["k"][li, 0].transpose(1, 0, 2), mode="drop"),
-                "v_pages": pool["v_pages"].at[:, page, slot].set(
-                    dense_blocks["v"][li, 0].transpose(1, 0, 2), mode="drop"),
+                "k_pages": pool_write_rows(
+                    pool["k_pages"], dense_blocks["k"][li, 0].transpose(1, 0, 2),
+                    page, slot),
+                "v_pages": pool_write_rows(
+                    pool["v_pages"], dense_blocks["v"][li, 0].transpose(1, 0, 2),
+                    page, slot),
             })
     return out
 

@@ -192,6 +192,92 @@ class TestPageAllocator:
         assert kv_cache.pages_for(9, 8) == 2
 
 
+class TestPoolWriteRows:
+    """``pool_write_rows`` (a row scatter on the pool's flat view) writes
+    the same pool, bit for bit, as the 4-D ``.at[:, page, slot]`` scatter
+    it replaced, in every way the program calls it.  No block table maps
+    page 0, so page 0 of every head must come back untouched: a dropped
+    row of head h sent to ``(h*N + N)*P + slot`` would land there."""
+
+    HKV, N, PG, D = 3, 7, 4, 8
+
+    def _pool(self, seed):
+        shape = (self.HKV, self.N, self.PG, self.D)
+        return jax.random.normal(jax.random.PRNGKey(seed), shape,
+                                 jnp.bfloat16)
+
+    def _decode(self, bt, lens, s):
+        """A decode (S=1) or verify (S>1) step's write, coordinates from
+        the attention layer's own ``_paged_token_coords``."""
+        from repro.models.attention import _paged_token_coords
+
+        pool = self._pool(1)
+        page, slot, _ = _paged_token_coords(
+            {"block_tables": jnp.asarray(bt, jnp.int32),
+             "len": jnp.asarray(lens, jnp.int32), "k_pages": pool},
+            "k_pages", s)
+        rows = jax.random.normal(jax.random.PRNGKey(2),
+                                 (self.HKV,) + page.shape + (self.D,),
+                                 jnp.bfloat16)
+        got = kv_cache.pool_write_rows(pool, rows, page, slot)
+        want = pool.at[:, page, slot].set(rows, mode="drop")
+        return pool, got, want
+
+    def _prompt(self, block_row, t, n, row0_pos, row_lo):
+        """``write_prompt_pages`` against the 4-D scatter at the
+        coordinates its docstring gives."""
+        pool = {"k_pages": self._pool(3), "v_pages": self._pool(4)}
+        ks = jax.random.split(jax.random.PRNGKey(5), 2)
+        dense = {x: jax.random.normal(k, (1, 1, t, self.HKV, self.D),
+                                      jnp.bfloat16)
+                 for x, k in zip(("k", "v"), ks)}
+        block_row = jnp.asarray(block_row, jnp.int32)
+        got = kv_cache.write_prompt_pages(
+            [pool], dense, block_row, jnp.int32(n), jnp.int32(row0_pos),
+            jnp.int32(row_lo))[0]
+        pos = jnp.arange(t) + row0_pos
+        page = block_row[jnp.clip(pos // self.PG, 0, block_row.shape[0] - 1)]
+        valid = (pos >= 0) & (pos >= row_lo) & (pos < n) & (page >= 0)
+        page = jnp.where(valid, page, self.N)
+        slot = pos % self.PG
+        want = {x: pool[f"{x}_pages"].at[:, page, slot].set(
+            dense[x][0, 0].transpose(1, 0, 2), mode="drop")
+            for x in ("k", "v")}
+        return (jnp.stack([pool["k_pages"], pool["v_pages"]]),
+                jnp.stack([got["k_pages"], got["v_pages"]]),
+                jnp.stack([want["k"], want["v"]]))
+
+    @pytest.mark.parametrize("case", [
+        "decode_inactive_slots", "verify_past_block_table",
+        "prompt_pad_rows_row_lo", "prompt_rolling_row0_neg"])
+    def test_matches_4d_scatter_and_drops_stay_drops(self, case):
+        if case == "decode_inactive_slots":
+            # slots 1 and 3 inactive (-1 rows); the others mid-page
+            bt = [[2, 3, -1], [-1, -1, -1], [5, -1, -1], [-1, -1, -1]]
+            before, got, want = self._decode(bt, [5, 0, 3, 0], 1)
+        elif case == "verify_past_block_table":
+            # slot 0 runs off its 3-page table (positions 11, 12, 13),
+            # slot 1's last page is unmapped, slot 2 is inactive
+            bt = [[2, 6, 4], [1, 5, -1], [-1, -1, -1]]
+            before, got, want = self._decode(bt, [11, 6, 0], 3)
+        elif case == "prompt_pad_rows_row_lo":
+            # 9 live rows of a 16-row bucket; rows < 4 sit in a shared
+            # prefix page; the table's tail is unmapped
+            before, got, want = self._prompt([2, 5, 3, -1], 16, 9, 0, 4)
+        else:
+            # SWA rolling buffer of 8 rows holding a 6-token prompt: its
+            # first two rows are positions -2 and -1, never written
+            before, got, want = self._prompt([6, 1, -1], 8, 6, -2, 0)
+        assert got.dtype == before.dtype and got.shape == before.shape
+        np.testing.assert_array_equal(np.asarray(got, np.float32),
+                                      np.asarray(want, np.float32))
+        assert not np.array_equal(np.asarray(got, np.float32),
+                                  np.asarray(before, np.float32))
+        np.testing.assert_array_equal(np.asarray(got[..., 0, :, :], np.float32),
+                                      np.asarray(before[..., 0, :, :],
+                                                 np.float32))
+
+
 class TestPagedModelDecode:
     """Model-level acceptance: batched paged decode at MIXED per-sequence
     lengths reproduces the dense ``generate`` path token-for-token."""

@@ -12,7 +12,10 @@ in this one file.
 
 from __future__ import annotations
 
+import dataclasses
+import math
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -22,6 +25,8 @@ from jax.sharding import NamedSharding, PartitionSpec as P, SingleDeviceSharding
 H, HKV, D = 16, 8, 128
 D_MODEL, D_FF = 1024, 3072
 SLOTS, PAGE, POOL_PAGES, PAGES_PER_SEQ = 8, 16, 512, 36
+# the serving cells' engine: 64 slots of 4,096 tokens over 391 pages of 256
+SERVE_SLOTS, SERVE_PAGE, SERVE_POOL_PAGES, SERVE_MAX_LEN = 64, 256, 391, 4096
 
 
 @pytest.fixture(scope="module")
@@ -132,3 +137,66 @@ def test_flash_per_shard_on_2x2_mesh(topo):
 
     with mesh:
         _compiles_to_kernel(fn, q, kv, kv)
+
+
+@pytest.fixture
+def chip_kernels(monkeypatch):
+    """Make the model dispatch its Pallas kernels, lowered for the
+    described chip (this process sees only the CPU backend)."""
+    from repro.models import layers
+
+    monkeypatch.setattr(layers, "_pallas_interpret", lambda: False)
+    prev = layers.set_attention_impl("pallas")
+    yield
+    layers.set_attention_impl(prev)
+
+
+def _pool_sized_copies(text, n_elements):
+    """Every ``copy`` in compiled HLO whose result holds as many elements
+    as a KV pool: the pool relaid out, whatever shape it is viewed in."""
+    found = []
+    for m in re.finditer(r"= \w+\[([\d,]*)\]\S* copy\(", text):
+        dims = [int(x) for x in m.group(1).split(",") if x]
+        if math.prod(dims) == n_elements:
+            found.append(m.group(0))
+    return found
+
+
+@pytest.mark.parametrize("program", ["serve_step", "write_prompt_pages"])
+def test_paged_kv_write_keeps_pool_in_place(one_chip, chip_kernels, program):
+    """The KV write of a decode step and of an admission's prompt write
+    leaves the donated bf16 pools where they are: no copy of a whole
+    pool, in its own shape or its flat row view (2 layers at Qwen3-0.6B
+    widths, the serving cells' slots, pages and pool)."""
+    from repro.configs.base import get_config
+    from repro.models import transformer as tf
+    from repro.serve import kv_cache
+    from repro.serve.step import make_serve_step
+
+    cfg = dataclasses.replace(get_config("qwen3_0p6b"), num_layers=2)
+
+    def shaped(fn):
+        return jax.tree.map(lambda a: _spec(a.shape, a.dtype, one_chip),
+                            jax.eval_shape(fn))
+
+    caches = shaped(lambda: tf.init_caches(
+        cfg, SERVE_SLOTS, SERVE_MAX_LEN, jnp.bfloat16, cache_layout="paged",
+        page_size=SERVE_PAGE, num_pages=SERVE_POOL_PAGES))
+    if program == "serve_step":
+        params = shaped(lambda: tf.init(jax.random.PRNGKey(0), cfg,
+                                        jnp.bfloat16))
+        tok = _spec((SERVE_SLOTS, 1), jnp.int32, one_chip)
+        step = jax.jit(make_serve_step(cfg), donate_argnums=(2,))
+        compiled = step.lower(params, tok, caches).compile()
+    else:
+        dense = shaped(lambda: tf.init_caches(cfg, 1, 1024, jnp.bfloat16))
+        row = _spec((SERVE_MAX_LEN // SERVE_PAGE,), jnp.int32, one_chip)
+        n = _spec((), jnp.int32, one_chip)
+        write = jax.jit(kv_cache.write_prompt_pages, donate_argnums=(0,))
+        compiled = write.lower(caches["blocks"], dense["blocks"], row,
+                               n, n, n).compile()
+    text = compiled.as_text()
+    pool = caches["blocks"][0]["k_pages"]
+    assert pool.shape == (HKV, SERVE_POOL_PAGES, SERVE_PAGE, D)
+    assert " scatter(" in text
+    assert _pool_sized_copies(text, math.prod(pool.shape)) == []
