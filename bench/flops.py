@@ -4,8 +4,9 @@ Counts are of the work a call *needs*, not of what an implementation
 happens to do: real tokens only (no pad rows), causal attention over
 the keys each query sees, and each byte of weights or cache read once.
 So a share of a roofline built on them cannot pass 100% unless the time
-leaves out part of the work.  All counts are for the dense GQA decoder
-(``model_type`` qwen2/qwen3), in the dtypes the cell runs.
+leaves out part of the work.  The sizes come from :class:`Dims`, which
+each model type's module (``bench/models/<model_type>.py``) fills from
+its configuration; the counts here name no model.
 """
 
 from __future__ import annotations
@@ -15,46 +16,33 @@ import dataclasses
 
 @dataclasses.dataclass(frozen=True)
 class Dims:
+    """What the counts read of a model.  ``flop_params`` and
+    ``read_params`` differ where a token passes through fewer weights
+    than a step reads, as with experts."""
     layers: int
-    d_model: int
-    heads: int
-    kv_heads: int
-    head_dim: int
-    d_ff: int
-    vocab: int
-    tied: bool
-
-    @classmethod
-    def of(cls, config: dict) -> "Dims":
-        return cls(config["num_hidden_layers"], config["hidden_size"],
-                   config["num_attention_heads"],
-                   config["num_key_value_heads"], config["head_dim"],
-                   config["intermediate_size"], config["vocab_size"],
-                   bool(config["tie_word_embeddings"]))
+    flop_params: int  # matmul weights of all layers one token passes through
+    read_params: int  # weights of all layers a step reads (norms, biases out)
+    head_params: int  # the output head (the embedding table when tied)
+    kv_row_elems: int  # cache elements of one token in one layer
+    attn_per_key: int  # operations of one query against one key, one layer
+    q_elems: int  # elements of one token's query (and of its output)
 
     @property
     def body_params(self) -> int:
-        """Matmul weights of all layers (norms and biases left out)."""
-        d, hd = self.d_model, self.head_dim
-        attn = d * self.heads * hd * 2 + d * self.kv_heads * hd * 2
-        return self.layers * (attn + 3 * d * self.d_ff)
-
-    @property
-    def head_params(self) -> int:
-        return self.d_model * self.vocab
+        return self.flop_params
 
     def weight_bytes(self, itemsize: int) -> int:
         """Bytes a step reads of the weights: every layer and the head
         (the embedding table doubles as the head when tied)."""
-        return (self.body_params + self.head_params) * itemsize
+        return (self.read_params + self.head_params) * itemsize
 
     def kv_row_bytes(self, itemsize: int) -> int:
-        """Cache bytes of one token in one layer (K and V)."""
-        return 2 * self.kv_heads * self.head_dim * itemsize
+        """Cache bytes of one token in one layer."""
+        return self.kv_row_elems * itemsize
 
     def attn_flops(self, keys: int) -> int:
         """One query against ``keys`` keys, all heads, one layer."""
-        return 4 * self.heads * self.head_dim * keys
+        return self.attn_per_key * keys
 
 
 def causal_keys(offset: int, n: int) -> int:
@@ -72,7 +60,7 @@ def paged_decode_call(dims: Dims, kv_lens, kv_itemsize: int,
     against its ``kv_len`` cached rows (the new one included)."""
     flops = sum(dims.attn_flops(c) for c in kv_lens)
     rows = sum(kv_lens)
-    qo = 2 * len(kv_lens) * dims.heads * dims.head_dim * q_itemsize
+    qo = 2 * len(kv_lens) * dims.q_elems * q_itemsize
     return flops, rows * dims.kv_row_bytes(kv_itemsize) + qo
 
 

@@ -4,6 +4,7 @@ step.  The references never import this module."""
 
 from __future__ import annotations
 
+import dataclasses
 import sys
 
 from bench.spec import ROOT
@@ -14,64 +15,18 @@ if _SRC not in sys.path:
 
 import jax  # noqa: E402
 
-# reference layout name -> path in the program's parameter tree
-_TOP = {
-    "embed": ("embed", "table"),
-    "final_norm": ("final_norm", "scale"),
-    "lm_head": ("lm_head", "w"),
-}
-_LAYER = {
-    "norm1": ("norm1", "scale"),
-    "wq": ("mixer", "wq", "w"),
-    "wk": ("mixer", "wk", "w"),
-    "wv": ("mixer", "wv", "w"),
-    "wo": ("mixer", "wo", "w"),
-    "bq": ("mixer", "wq", "b"),
-    "bk": ("mixer", "wk", "b"),
-    "bv": ("mixer", "wv", "b"),
-    "q_norm": ("mixer", "q_norm", "scale"),
-    "k_norm": ("mixer", "k_norm", "scale"),
-    "norm2": ("norm2", "scale"),
-    "w_gate": ("ffn", "w_gate", "w"),
-    "w_up": ("ffn", "w_up", "w"),
-    "w_down": ("ffn", "w_down", "w"),
-}
-
-
 _CONFIGS: dict = {}
 
 
-def model_config(config: dict):
-    """The program's ``ModelConfig`` for a configuration file; one object
-    per configuration in a process, so the engine's jitted steps (kept
-    per config object) are traced once."""
+def model_config(model, config: dict):
+    """The program's ``ModelConfig`` for a configuration file (built by
+    the model type's module); one object per configuration in a process,
+    so the engine's jitted steps (kept per config object) are traced
+    once."""
     key = repr(sorted((k, repr(v)) for k, v in config.items()))
     if key not in _CONFIGS:
-        _CONFIGS[key] = _model_config(config)
+        _CONFIGS[key] = model.program_config(config)
     return _CONFIGS[key]
-
-
-def _model_config(config: dict):
-    from repro.configs.base import ModelConfig
-
-    if config["model_type"] not in ("qwen2", "qwen3"):
-        raise ValueError(f"no program mapping for {config['model_type']!r}")
-    return ModelConfig(
-        name=config["name"], family="dense",
-        num_layers=config["num_hidden_layers"],
-        d_model=config["hidden_size"],
-        num_heads=config["num_attention_heads"],
-        kv_heads=config["num_key_value_heads"],
-        head_dim=config["head_dim"],
-        d_ff=config["intermediate_size"],
-        vocab=config["vocab_size"],
-        qkv_bias=bool(config.get("attention_bias", False)),
-        qk_norm=bool(config.get("qk_norm", False)),
-        rope_theta=float(config["rope_theta"]),
-        norm_eps=float(config["rms_norm_eps"]),
-        tie_embeddings=bool(config["tie_word_embeddings"]),
-        max_seq_len=int(config["max_position_embeddings"]),
-    )
 
 
 def _set(tree: dict, path: tuple, value) -> None:
@@ -86,39 +41,41 @@ def _get(tree: dict, path: tuple):
     return tree
 
 
-def to_program(w: dict) -> dict:
-    """Reference-layout weights as the program's parameter tree."""
+def to_program(model, w: dict) -> dict:
+    """Reference-layout weights as the program's parameter tree, by the
+    module's ``LEAVES`` ("group/leaf" names inside a stacked group)."""
     out: dict = {}
-    for name, path in _TOP.items():
-        if name in w:
-            _set(out, path, w[name])
-    for name, leaf in w["layers"].items():
-        _set(out, ("blocks",) + _LAYER[name], leaf)
+    for name, leaf in w.items():
+        if isinstance(leaf, dict):
+            for k, v in leaf.items():
+                _set(out, model.LEAVES[f"{name}/{k}"], v)
+        else:
+            _set(out, model.LEAVES[name], leaf)
     return out
 
 
-def from_program(tree: dict) -> dict:
+def from_program(model, tree: dict) -> dict:
     """The inverse of :func:`to_program` (for optimizer moments too)."""
-    out: dict = {"layers": {}}
-    for name, path in _TOP.items():
+    out: dict = {}
+    for name, path in model.LEAVES.items():
         try:
-            out[name] = _get(tree, path)
+            leaf = _get(tree, path)
         except KeyError:
-            pass
-    for name, path in _LAYER.items():
-        try:
-            out["layers"][name] = _get(tree["blocks"], path)
-        except KeyError:
-            pass
+            continue
+        if "/" in name:
+            group, k = name.split("/")
+            out.setdefault(group, {})[k] = leaf
+        else:
+            out[name] = leaf
     return out
 
 
-def check_layout(w: dict, cfg) -> None:
+def check_layout(model, w: dict, cfg) -> None:
     """The mapped weights have exactly the program's parameter shapes."""
     from repro.models import transformer as tf
 
     want = jax.eval_shape(lambda: tf.init(jax.random.PRNGKey(0), cfg))
-    got = jax.tree.map(lambda a: a.shape, to_program(w))
+    got = jax.tree.map(lambda a: a.shape, to_program(model, w))
     want = jax.tree.map(lambda a: a.shape, want)
     if got != want:
         raise ValueError(f"weight layout differs from the program's:\n"
@@ -175,34 +132,118 @@ def use_compile_cache() -> str:
     return path
 
 
-def train_step(cfg, opt: dict):
-    """The program's fused training step and its AdamW, jitted and
-    placed as its own launcher does on a one-device mesh; returns
-    (init_state(params) -> placed state, jitted step, mesh)."""
-    from repro.ft.elastic import make_mesh_for
-    from repro.launch.train import jit_train_step
-    from repro.optim import adamw
-    from repro.train.step import make_train_step
+@dataclasses.dataclass
+class Trainer:
+    """The program's training step as a job states it: ``step`` jitted
+    with the state's placement, ``mesh`` to run it under."""
+    model: object
+    cfg: object
+    mesh: object
+    step: object
+    bounds: tuple | None  # the pipeline's layer cuts, None when fused
+    batch_sharding: object | None
 
-    acfg = adamw.AdamWConfig(
+    def feed(self, tokens) -> dict:
+        import jax.numpy as jnp
+
+        if self.batch_sharding is None:
+            return {"tokens": jnp.asarray(tokens)}
+        return {"tokens": jax.device_put(tokens, self.batch_sharding)}
+
+    def _canonical(self, tree):
+        if self.bounds is None:
+            return tree
+        from repro.dist.pipeline import unpad_pipeline_params
+
+        return unpad_pipeline_params(tree, self.cfg, self.bounds)
+
+    def params(self, state) -> dict:
+        """The state's parameters in the reference layout."""
+        return from_program(self.model, self._canonical(state["params"]))
+
+    def moments(self, state) -> dict:
+        """First moments of a train state, in the reference layout."""
+        return from_program(self.model, self._canonical(state["opt"].mu))
+
+
+def _adamw(job: dict):
+    from repro.optim import adamw
+
+    opt = job["optimizer"]
+    return adamw.AdamWConfig(
         lr=opt["lr"], b1=opt["b1"], b2=opt["b2"], eps=opt["eps"],
         weight_decay=opt["weight_decay"], grad_clip=opt["grad_clip"],
         warmup_steps=opt["warmup_steps"], total_steps=opt["total_steps"],
-        min_lr_ratio=opt["min_lr_ratio"])
-    mesh = make_mesh_for(jax.devices()[:1])
-    fn = make_train_step(cfg, acfg)
+        min_lr_ratio=opt["min_lr_ratio"],
+        moments_dtype=job.get("moments_dtype", "float32"))
 
-    def place(params):
-        import jax.numpy as jnp
 
-        state = {"params": params, "opt": adamw.init(params, jnp.float32),
-                 "step": jnp.zeros((), jnp.int32)}
+def train_parts(cfg, job: dict, devices):
+    """What the job's training step is made of, on ``devices``:
+    (mesh, step function, build(params) -> train state, the pipeline's
+    layer cuts or None, the state's sharding strategy).  Without
+    ``mesh`` in the job: the fused step on a one-device mesh.  With
+    ``"mesh": {"stages": S, "data": D}``: the pipeline step
+    (``schedule``, ``microbatches``) on S x D devices, the layers cut
+    evenly and padded into the stage layout."""
+    import jax.numpy as jnp
+
+    import repro.train.step as tstep
+    from repro.optim import adamw
+
+    acfg = _adamw(job)
+    mdt = jnp.dtype(acfg.moments_dtype)
+    grid = job.get("mesh")
+    if grid is None:
+        from repro.ft.elastic import make_mesh_for
+
+        mesh = make_mesh_for(list(devices)[:1])
+        fn = tstep.make_train_step(cfg, acfg)
+        bounds, strategy = None, "fused"
+    else:
+        from repro.dist.pipeline import even_boundaries
+        from repro.launch.mesh import make_mesh
+
+        stages, data = int(grid["stages"]), int(grid["data"])
+        mesh = make_mesh((data, stages), ("data", "model"),
+                         devices=list(devices)[:stages * data])
+        bounds = even_boundaries(cfg.num_layers, stages)
+        fn = tstep.make_pipeline_train_step(
+            cfg, acfg, mesh, num_microbatches=int(job["microbatches"]),
+            boundaries=bounds, schedule=job.get("schedule", "1f1b"))
+        strategy = "pipeline"
+
+    def build(params):
+        if bounds is not None:
+            from repro.dist.pipeline import pad_pipeline_params
+
+            params = pad_pipeline_params(params, cfg, bounds)
+        return {"params": params, "opt": adamw.init(params, mdt),
+                "step": jnp.zeros((), jnp.int32)}
+
+    return mesh, fn, build, bounds, strategy
+
+
+def trainer(model, cfg, job: dict, params) -> tuple[Trainer, dict]:
+    """The program's training step and its AdamW as the job states them
+    (:func:`train_parts`), jitted and placed as its own launcher does,
+    with the state built from ``params`` (the program's tree).  The
+    pipeline's state is built in place on its mesh and its batch split
+    over the data axis."""
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from repro.ft.elastic import state_shardings
+    from repro.launch.train import jit_train_step
+
+    mesh, fn, build, bounds, strategy = train_parts(cfg, job, jax.devices())
+    if bounds is None:
         with mesh:
-            return jit_train_step(fn, state, mesh, "fused")
-
-    return place, mesh
-
-
-def adam_moments(state) -> dict:
-    """First moments of a train state, in the reference layout."""
-    return from_program(state["opt"].mu)
+            state, step, _ = jit_train_step(fn, build(params), mesh, strategy)
+        return Trainer(model, cfg, mesh, step, None, None), state
+    sshard = state_shardings(jax.eval_shape(build, params), mesh, strategy)
+    batch = NamedSharding(mesh, P("data"))
+    with mesh:
+        state = jax.jit(build, out_shardings=sshard)(params)
+        step = jax.jit(fn, in_shardings=(sshard, {"tokens": batch}),
+                       out_shardings=(sshard, None), donate_argnums=(0,))
+    return Trainer(model, cfg, mesh, step, bounds, batch), state

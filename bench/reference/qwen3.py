@@ -106,7 +106,8 @@ def attention(q, k, v, mode):
 
 def layer(x, p, config, positions, mode):
     eps = config["rms_norm_eps"]
-    hd = config["head_dim"]
+    hd = (config.get("head_dim")
+          or config["hidden_size"] // config["num_attention_heads"])
     h = rmsnorm(x, p["norm1"], eps)
     q = mm(h, p["wq"], mode)
     k = mm(h, p["wk"], mode)

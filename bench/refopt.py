@@ -2,6 +2,8 @@
 reference's optimizer.  Gradients clipped to a global norm, bias-
 corrected moments, decoupled weight decay on every parameter, learning
 rate warmed up linearly and then decayed on a cosine to ``min_lr_ratio``.
+The arithmetic is float32; parameters are rounded back to their own
+dtype after each update and moments to the dtype the job holds them in.
 """
 
 from __future__ import annotations
@@ -19,9 +21,13 @@ def lr_at(opt: dict, step):
                                + (1 - opt["min_lr_ratio"]) * cos)
 
 
-def init(params):
-    z = jax.tree.map(jnp.zeros_like, params)
-    return {"mu": z, "nu": jax.tree.map(jnp.zeros_like, params)}
+def init(params, moments_dtype="float32"):
+    """Zero moments, placed as the parameters are."""
+    def zeros(p):
+        return jnp.zeros_like(p, dtype=moments_dtype)
+
+    return {"mu": jax.tree.map(zeros, params),
+            "nu": jax.tree.map(zeros, params)}
 
 
 def clip(grads, max_norm: float):
@@ -36,13 +42,17 @@ def update(opt: dict, params, grads, state, step: int):
     clipped grads)."""
     g = clip(grads, opt["grad_clip"])
     b1, b2 = opt["b1"], opt["b2"]
-    mu = jax.tree.map(lambda m, x: b1 * m + (1 - b1) * x, state["mu"], g)
-    nu = jax.tree.map(lambda v, x: b2 * v + (1 - b2) * x * x, state["nu"], g)
+    f32 = jnp.float32
+    mu = jax.tree.map(lambda m, x: (b1 * m.astype(f32) + (1 - b1) * x)
+                      .astype(m.dtype), state["mu"], g)
+    nu = jax.tree.map(lambda v, x: (b2 * v.astype(f32) + (1 - b2) * x * x)
+                      .astype(v.dtype), state["nu"], g)
     lr = lr_at(opt, step)
     bc1, bc2 = 1 - b1 ** step, 1 - b2 ** step
 
     def upd(p, m, v):
+        m, v, pf = m.astype(f32), v.astype(f32), p.astype(f32)
         d = (m / bc1) / (jnp.sqrt(v / bc2) + opt["eps"])
-        return p - lr * (d + opt["weight_decay"] * p)
+        return (pf - lr * (d + opt["weight_decay"] * pf)).astype(p.dtype)
 
     return jax.tree.map(upd, params, mu, nu), {"mu": mu, "nu": nu}, g

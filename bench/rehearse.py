@@ -8,7 +8,8 @@ Serving cells compile the engine's paged decode step at the cell's
 slots, pool and dtypes, and its prefill and page scatter at the
 smallest and the largest dense-cache bucket; the training cell compiles
 its train step at each batch of ``--batches`` (the largest that fits is
-the one to state in the job file).
+the one to state in the job file), on the job's mesh of described chips
+where it states one.
 """
 
 from __future__ import annotations
@@ -59,13 +60,13 @@ def serve(cell, sh):
     from repro.serve import kv_cache
     from repro.serve.step import make_prefill_step, make_serve_step
 
-    mix, config = cell.traffic, cell.config
+    mix, config, model = cell.traffic, cell.config, cell.model
     eng = mix["engine"]
-    cfg = program.model_config(config)
+    cfg = program.model_config(model, config)
     dt = jnp.dtype(eng["weights_dtype"])
     w = jax.eval_shape(lambda: weights._make(
-        weights.key_for(0), config, dt))
-    params = _shaped(program.to_program(w), sh)
+        weights.key_for(0), model, config, dt))
+    params = _shaped(program.to_program(model, w), sh)
     slots, chunk = int(eng["max_slots"]), int(eng["prefill_chunk"])
     max_len = int(mix["prompt_tokens"]["max"]) + int(mix["output_tokens"]["max"])
     pg = int(eng["page_size"])
@@ -97,27 +98,32 @@ def serve(cell, sh):
                 dense["blocks"], row, n, n, n)
 
 
-def train(cell, sh, batches):
-    from repro.optim import adamw as padamw
-    from repro.train.step import make_train_step
+def train(cell, topo, batches):
+    """The job's train step, placed as the harness places it: one chip
+    for the fused step, the job's mesh of described chips for the
+    pipeline."""
+    from jax.sharding import NamedSharding, PartitionSpec as P
 
-    job, config = cell.traffic, cell.config
-    cfg = program.model_config(config)
-    o = job["optimizer"]
-    acfg = padamw.AdamWConfig(**{k: o[k] for k in (
-        "lr", "b1", "b2", "eps", "weight_decay", "grad_clip",
-        "warmup_steps", "total_steps", "min_lr_ratio")})
+    from repro.ft.elastic import state_shardings
+
+    job, config, model = cell.traffic, cell.config, cell.model
+    cfg = program.model_config(model, config)
     w = jax.eval_shape(lambda: weights._make(
-        weights.key_for(0), config, jnp.dtype(job["params_dtype"])))
-    params = program.to_program(w)
-    state = _shaped({"params": params,
-                     "opt": jax.eval_shape(lambda: padamw.init(params)),
-                     "step": jax.ShapeDtypeStruct((), jnp.int32)}, sh)
+        weights.key_for(0), model, config, jnp.dtype(job["params_dtype"])))
+    mesh, fn, build, bounds, strategy = program.train_parts(
+        cfg, job, topo.devices)
+    like = jax.eval_shape(build, program.to_program(model, w))
+    sshard = state_shardings(like, mesh, strategy)
+    state = jax.tree.map(lambda a, s: jax.ShapeDtypeStruct(
+        a.shape, a.dtype, sharding=s), like, sshard)
+    data = NamedSharding(mesh, P("data") if bounds else P())
     for b in batches:
         batch = {"tokens": jax.ShapeDtypeStruct(
-            (b, int(job["seq_len"]) + 1), jnp.int32, sharding=sh)}
-        _report(f"train step batch {b}",
-                jax.jit(make_train_step(cfg, acfg), donate_argnums=(0,)),
+            (b, int(job["seq_len"]) + 1), jnp.int32, sharding=data)}
+        with mesh:
+            _report(f"train step batch {b}", jax.jit(
+                fn, in_shardings=(sshard, {"tokens": data}),
+                out_shardings=(sshard, None), donate_argnums=(0,)),
                 state, batch)
 
 
@@ -139,7 +145,7 @@ def main(argv=None):
     else:
         batches = [int(x) for x in args.batches.split(",") if x] or \
             [int(cell.traffic["batch"])]
-        train(cell, sh, batches)
+        train(cell, topo, batches)
 
 
 if __name__ == "__main__":

@@ -124,7 +124,6 @@ def run(workload: str, seed: int, seconds: float, trace: bool,
         program.refuse_unless_kernels()
     import jax
 
-    from bench.flops import Dims
     from bench.peaks import peaks_for
 
     peaks = peaks_for(jax.devices()[0].device_kind) if require_chip else \
@@ -156,7 +155,7 @@ def run(workload: str, seed: int, seconds: float, trace: bool,
             "failed": int(res["failed"])}
     if trace:
         tr, path = tracer.load()
-        ctx = SimpleNamespace(cell=cell, dims=Dims.of(cell.config),
+        ctx = SimpleNamespace(cell=cell, dims=cell.model.dims(cell.config),
                               peaks=peaks, trace=tr, root=root, **res)
         line["metrics"] = per_layer(cell, ctx)
         line["device"] = device_info(res["peak"])

@@ -246,7 +246,7 @@ def pick_sample(judged, check: dict, seed: int) -> list:
     return pick
 
 
-def reference_gaps(config: dict, ref, seed: int, dtype, sample,
+def reference_gaps(model, config: dict, ref, seed: int, dtype, sample,
                    max_len: int, max_out: int, control: bool = False):
     """For each sampled request, how far each served token's logit lies
     below the reference's best at its position (and, with ``control``,
@@ -254,7 +254,7 @@ def reference_gaps(config: dict, ref, seed: int, dtype, sample,
     import jax
     import jax.numpy as jnp
 
-    w = weights.make(config, seed, dtype)
+    w = weights.make(model, config, seed, dtype)
     fn = _gap_fn(ref, config, max_len, max_out, control)
     out = []
     for t in sample:
@@ -325,13 +325,13 @@ def run_cell(cell, seed: int, seconds: float, tracer, started: float,
     from bench import program
     from bench.spec import reference_module
 
-    mix, config = cell.traffic, cell.config
+    mix, config, model = cell.traffic, cell.config, cell.model
     eng_s = mix["engine"]
-    cfg = program.model_config(config)
+    cfg = program.model_config(model, config)
     dtype = jnp.dtype(eng_s["weights_dtype"])
-    w = weights.make(config, seed, dtype)
-    program.check_layout(w, cfg)
-    params = program.to_program(w)
+    w = weights.make(model, config, seed, dtype)
+    program.check_layout(model, w, cfg)
+    params = program.to_program(model, w)
     del w
     max_len = int(mix["prompt_tokens"]["max"]) + int(mix["output_tokens"]["max"])
     eng = program.serving_engine(params, cfg, eng_s, max_len,
@@ -366,7 +366,7 @@ def run_cell(cell, seed: int, seconds: float, tracer, started: float,
     gc.collect()
     t_ref = clock()
     gaps = [] if check == "none" else reference_gaps(
-        config, reference_module(config), seed, dtype, sample, max_len,
+        model, config, reference_module(config), seed, dtype, sample, max_len,
         int(mix["output_tokens"]["max"]), control=check == "control")
     gap = max(float(np.max(g["gap"])) for g in gaps) if gaps else float("inf")
     control = ({"logit_gap": max(float(np.max(g["control_gap"]))

@@ -1,14 +1,22 @@
 """Finds a cell's files by the names ``BENCHMARK.json`` gives them.
 
     bench/configs/<config>.json      a model configuration (sizes)
+    bench/models/<model_type>.py     what the harness knows of a model
+                                     type: the program's configuration,
+                                     weight shapes and leaf map, and the
+                                     sizes the counts of flops.py read
     bench/reference/<model_type>.py  its plain jnp reference
-    bench/traffic/<traffic>.json     a traffic mix or training job
+    bench/traffic/<traffic>.json     a traffic mix or training job; a
+                                     job may state "mesh" ({"stages",
+                                     "data"}), "schedule", "microbatches",
+                                     "params_dtype" and "moments_dtype"
     bench/cells/<workload>.json      what belongs to one cell: memory
                                      sizes and the limits of `correct`
     bench/metrics/<metric>.py        the reader of one per-layer metric
 
-A later cell is added by adding such files and an entry in
-``BENCHMARK.json``; nothing here names a cell.
+A later cell, or a new model type, is added by adding such files and an
+entry in ``BENCHMARK.json``; nothing outside ``bench/models`` and
+``bench/reference`` names a model type, a cell or a model's leaves.
 """
 
 from __future__ import annotations
@@ -32,6 +40,7 @@ class Cell:
     settings: dict
     end_to_end: list  # metric entries of BENCHMARK.json this cell reports
     per_layer: list
+    model: object  # the configuration's bench/models module
 
 
 def _load_json(path: Path) -> dict:
@@ -61,7 +70,8 @@ def load_cell(workload: str, root: Path = ROOT) -> Cell:
     names = {m["name"] for m in e2e}
     per_layer = [m for m in bench["per_layer"] if _reports(m, workload, names)]
     return Cell(workload, int(w["chips"]), w["config"], config,
-                w["traffic"], traffic, settings, e2e, per_layer)
+                w["traffic"], traffic, settings, e2e, per_layer,
+                model_module(config, root))
 
 
 def _load_module(path: Path, name: str):
@@ -85,3 +95,10 @@ def reference_module(config: dict, root: Path = ROOT):
     kind = config["model_type"]
     return _load_module(root / "bench" / "reference" / f"{kind}.py",
                         f"bench_reference_{kind}")
+
+
+def model_module(config: dict, root: Path = ROOT):
+    """The harness's module for the configuration's ``model_type``."""
+    kind = config["model_type"]
+    return _load_module(root / "bench" / "models" / f"{kind}.py",
+                        f"bench_model_{kind}")

@@ -42,22 +42,50 @@ CONTROL = "fp8"
 STILL_LEAF = 1e-3
 
 
-def leaf_norms(tree: dict) -> dict:
-    """Norm of each tensor of a reference-layout tree, the stacked layer
-    tensors split per layer (``layers.<i>.<name>``)."""
+def _per_layer(tree: dict):
+    """(key, leaf, stacked) of a reference-layout tree: every dict-valued
+    entry is a stacked group whose leaves carry a leading layer axis,
+    keyed ``<group>.<i>.<name>`` per layer."""
+    for name, leaf in tree.items():
+        if isinstance(leaf, dict):
+            for k, v in leaf.items():
+                yield f"{name}.{{}}.{k}", v, True
+        else:
+            yield name, leaf, False
+
+
+def _norms_into(out: dict, key: str, v, stacked: bool) -> None:
     import jax.numpy as jnp
 
-    out = {}
-    for name, leaf in tree.items():
-        if name == "layers":
-            for k, v in leaf.items():
-                per = jnp.sqrt(jnp.sum(jnp.square(v.astype(jnp.float32)),
-                                       axis=tuple(range(1, v.ndim))))
-                for i, x in enumerate(np.asarray(per)):
-                    out[f"layers.{i}.{k}"] = float(x)
-        else:
-            out[name] = float(jnp.sqrt(jnp.sum(jnp.square(
-                leaf.astype(jnp.float32)))))
+    sq = jnp.square(v.astype(jnp.float32))
+    if stacked:
+        per = jnp.sqrt(jnp.sum(sq, axis=tuple(range(1, v.ndim))))
+        for i, x in enumerate(np.asarray(per)):
+            out[key.format(i)] = float(x)
+    else:
+        out[key] = float(jnp.sqrt(jnp.sum(sq)))
+
+
+def leaf_norms(tree: dict) -> dict:
+    """Norm of each tensor of a reference-layout tree, the stacked layer
+    tensors split per layer."""
+    out: dict = {}
+    for key, v, stacked in _per_layer(tree):
+        _norms_into(out, key, v, stacked)
+    return out
+
+
+def change_norms(new: dict, old: dict) -> dict:
+    """:func:`leaf_norms` of ``new - old`` in float32, one leaf at a
+    time, each leaf of ``old`` first placed as ``new``'s is."""
+    import jax
+
+    before = {key: v for key, v, _ in _per_layer(old)}
+    out: dict = {}
+    for key, v, stacked in _per_layer(new):
+        was = jax.device_put(before[key], v.sharding)
+        _norms_into(out, key, v.astype("float32") - was.astype("float32"),
+                    stacked)
     return out
 
 
@@ -65,14 +93,13 @@ def host_leaves(tree: dict) -> dict:
     """A reference-layout tree as float32 host arrays, keyed and split
     per layer as :func:`leaf_norms` keys them."""
     out = {}
-    for name, leaf in tree.items():
-        if name == "layers":
-            for k, v in leaf.items():
-                a = np.asarray(v, np.float32)
-                for i in range(a.shape[0]):
-                    out[f"layers.{i}.{k}"] = a[i]
+    for key, v, stacked in _per_layer(tree):
+        a = np.asarray(v, np.float32)
+        if stacked:
+            for i in range(a.shape[0]):
+                out[key.format(i)] = a[i]
         else:
-            out[name] = np.asarray(leaf, np.float32)
+            out[key] = a
     return out
 
 
@@ -91,34 +118,51 @@ def worst_gap(got: dict, want: dict, keep=None) -> float:
     return max(abs(got[k] - want[k]) / max(want[k], med) for k in names)
 
 
-def _diff(a: dict, b: dict) -> dict:
+def reference_mesh(chips: int):
+    """A one-axis mesh over the cell's chips, which the reference's
+    weights and optimizer state are spread over when one chip cannot
+    hold them (None for a one-chip cell)."""
     import jax
 
-    return jax.tree.map(lambda x, y: x.astype("float32") - y.astype("float32"),
-                        a, b)
+    from jax.sharding import Mesh
+
+    if chips <= 1:
+        return None
+    return Mesh(np.asarray(jax.devices()[:chips]), ("chips",))
 
 
-def reference(config: dict, job: dict, seed: int, data, steps: int,
-              mode: str = "f32", rows: int | None = None):
+def reference(model, config: dict, job: dict, seed: int, data, steps: int,
+              mode: str = "f32", rows: int | None = None, mesh=None):
     """Losses, first clipped gradient norms, change norms and first
     clipped gradient (host arrays) of the plain reference over
     ``steps`` steps (matmuls in ``mode``; with ``rows``, on the first
-    ``rows`` rows of each batch only)."""
+    ``rows`` rows of each batch only).  Parameters are held in the job's
+    ``params_dtype`` and moments in its ``moments_dtype``; the loss,
+    its gradient and the update are computed in float32.  With ``mesh``
+    every weight and moment is spread over its devices and the jitted
+    steps are partitioned by XLA from that placement."""
     import jax
+    import jax.numpy as jnp
 
     from bench.spec import reference_module
 
     ref = reference_module(config)
     opt = job["optimizer"]
-    vg = jax.jit(jax.value_and_grad(
-        lambda w, rows: ref.loss(w, config, rows, mode)))
+    f32 = jnp.float32
+
+    def value_and_grad(w, rows):
+        w32 = jax.tree.map(lambda a: a.astype(f32), w)
+        return jax.value_and_grad(
+            lambda p: ref.loss(p, config, rows, mode))(w32)
+
+    vg = jax.jit(value_and_grad)
     upd = jax.jit(lambda w, g, s, k: refopt.update(opt, w, g, s, k),
                   static_argnums=3, donate_argnums=(0, 2))
-    w = weights.make(config, seed, job["params_dtype"])
-    state = refopt.init(w)
+    w = weights.make(model, config, seed, job["params_dtype"], mesh)
+    state = refopt.init(w, job.get("moments_dtype", "float32"))
     losses, gnorm, grad = [], None, None
     for i in range(steps):
-        loss, g = vg(w, jax.numpy.asarray(data.batch(i)[:rows]))
+        loss, g = vg(w, jnp.asarray(data.batch(i)[:rows]))
         losses.append(float(loss))
         w, state, gc_ = upd(w, g, state, i + 1)
         if i == 0:
@@ -126,8 +170,8 @@ def reference(config: dict, job: dict, seed: int, data, steps: int,
             grad = host_leaves(gc_)
         del g, gc_
     del state
-    w0 = weights.make(config, seed, job["params_dtype"])
-    dnorm = leaf_norms(_diff(w, w0))
+    w0 = weights.make(model, config, seed, job["params_dtype"], mesh)
+    dnorm = change_norms(w, w0)
     return losses, gnorm, dnorm, grad
 
 
@@ -156,33 +200,33 @@ def run_cell(cell, seed: int, seconds: float, tracer, started: float,
 
     from bench import program
 
-    job, config = cell.traffic, cell.config
-    cfg = program.model_config(config)
-    place, mesh = program.train_step(cfg, job["optimizer"])
+    job, config, model = cell.traffic, cell.config, cell.model
+    cfg = program.model_config(model, config)
     data = traffic.PackedDocs(job, seed, int(config["vocab_size"]),
                               int(config["eos_token_id"]))
-    w = weights.make(config, seed, job["params_dtype"])
-    program.check_layout(w, cfg)
-    state, step, _ = place(program.to_program(w))
+    w = weights.make(model, config, seed, job["params_dtype"])
+    program.check_layout(model, w, cfg)
+    run, state = program.trainer(model, cfg, job,
+                                 program.to_program(model, w))
     del w
 
     def feed(k):
-        return {"tokens": jnp.asarray(data.batch(k))}
+        return run.feed(data.batch(k))
 
-    with mesh:
-        compiled = step.lower(state, feed(0)).compile()
+    with run.mesh:
+        compiled = run.step.lower(state, feed(0)).compile()
         losses = []
         b1 = job["optimizer"]["b1"]
         for k in range(CHECK_STEPS):
             state, m = compiled(state, feed(k))
             losses.append(float(m["loss"]))
             if k == 0:
-                mu = program.adam_moments(state)
+                mu = run.moments(state)
                 gnorm = {n: v / (1 - b1) for n, v in leaf_norms(mu).items()}
                 grad = {n: v / (1 - b1) for n, v in host_leaves(mu).items()}
                 del mu
-        w0 = weights.make(config, seed, job["params_dtype"])
-        dnorm = leaf_norms(_diff(program.from_program(state["params"]), w0))
+        w0 = weights.make(model, config, seed, job["params_dtype"])
+        dnorm = change_norms(run.params(state), w0)
         del w0
         jax.block_until_ready(state)
         setup_s = clock() - started
@@ -214,10 +258,11 @@ def run_cell(cell, seed: int, seconds: float, tracer, started: float,
     steps = k - CHECK_STEPS
     peak = peak_bytes()
     mem = compiled.memory_analysis()
-    del state, compiled, m, pending
+    del state, compiled, m, pending, run
     gc.collect()
     t_ref = clock()
-    want = reference(config, job, seed, data, CHECK_STEPS)
+    mesh = reference_mesh(cell.chips)
+    want = reference(model, config, job, seed, data, CHECK_STEPS, mesh=mesh)
     r_losses, r_gnorm = want[0], want[1]
     med = float(np.median(list(r_gnorm.values())))
     checks = compare((losses, gnorm, dnorm, grad), want)
@@ -225,11 +270,12 @@ def run_cell(cell, seed: int, seconds: float, tracer, started: float,
     control = None
     if check == "control":
         control = {
-            CONTROL: compare(reference(config, job, seed, data, CHECK_STEPS,
-                                       mode=CONTROL), want),
+            CONTROL: compare(reference(model, config, job, seed, data,
+                                       CHECK_STEPS, mode=CONTROL, mesh=mesh),
+                             want),
             "half_batch": compare(reference(
-                config, job, seed, data, CHECK_STEPS,
-                rows=int(job["batch"]) // 2), want),
+                model, config, job, seed, data, CHECK_STEPS,
+                rows=int(job["batch"]) // 2, mesh=mesh), want),
         }
     return {
         "control": control,

@@ -1,9 +1,11 @@
 """Weights of a configuration, made on the device from ``--seed`` in one
-jitted call, in the layout of the plain references (``bench/reference``).
+jitted call, in the layout of the plain references (``bench/reference``)
+that the model type's module (``bench/models``) gives as shapes.
 
 Dense weights are normal with variance 1/fan_in, the embedding normal
 with std 0.02, norm scales 1 + 0.1 * normal (so that a scale wired to
-the wrong place shows), biases normal with std 0.02.
+the wrong place shows), biases normal with std 0.02; the module's
+``draw(name)`` says which a weight is.
 """
 
 from __future__ import annotations
@@ -12,8 +14,6 @@ import functools
 
 import jax
 import jax.numpy as jnp
-
-from bench.flops import Dims
 
 
 def key_for(seed: int, stream: int = 0):
@@ -25,54 +25,32 @@ def key_for(seed: int, stream: int = 0):
     return jax.random.fold_in(key, stream)
 
 
-def shapes(config: dict) -> dict:
-    d = Dims.of(config)
-    L, D, hd = d.layers, d.d_model, d.head_dim
-    layers = {
-        "norm1": (L, D),
-        "wq": (L, D, d.heads * hd),
-        "wk": (L, D, d.kv_heads * hd),
-        "wv": (L, D, d.kv_heads * hd),
-        "wo": (L, d.heads * hd, D),
-        "norm2": (L, D),
-        "w_gate": (L, D, d.d_ff),
-        "w_up": (L, D, d.d_ff),
-        "w_down": (L, d.d_ff, D),
-    }
-    if config.get("qk_norm"):
-        layers.update(q_norm=(L, hd), k_norm=(L, hd))
-    if config.get("attention_bias"):
-        layers.update(bq=(L, d.heads * hd), bk=(L, d.kv_heads * hd),
-                      bv=(L, d.kv_heads * hd))
-    out = {"embed": (d.vocab, D), "final_norm": (D,), "layers": layers}
-    if not d.tied:
-        out["lm_head"] = (D, d.vocab)
-    return out
-
-
-def _draw(key, name: str, shape, dtype):
-    base = name.split("/")[-1]
-    if base.startswith("norm") or base.endswith("_norm"):
+def _draw(key, kind: str, shape, dtype):
+    if kind == "scale":
         x = 1.0 + 0.1 * jax.random.normal(key, shape, jnp.float32)
-    elif base == "embed":
+    elif kind in ("embedding", "bias"):
         x = 0.02 * jax.random.normal(key, shape, jnp.float32)
-    elif base in ("bq", "bk", "bv"):
-        x = 0.02 * jax.random.normal(key, shape, jnp.float32)
-    else:  # (..., fan_in, fan_out)
+    elif kind == "matrix":  # (..., fan_in, fan_out)
         x = jax.random.normal(key, shape, jnp.float32) * shape[-2] ** -0.5
+    else:
+        raise ValueError(f"unknown way to draw a weight: {kind!r}")
     return x.astype(dtype)
 
 
-def _make(key, config, dtype):
+def _flat(model, config) -> list[tuple[str, tuple]]:
+    """(name, shape) of every weight, a stacked group's as "group/leaf"."""
     flat = []
-    for name, shape in shapes(config).items():
+    for name, shape in model.shapes(config).items():
         if isinstance(shape, dict):
             flat += [(f"{name}/{k}", v) for k, v in shape.items()]
         else:
             flat.append((name, shape))
+    return sorted(flat)
+
+
+def _nest(flat: dict) -> dict:
     out: dict = {}
-    for i, (name, shape) in enumerate(sorted(flat)):
-        leaf = _draw(jax.random.fold_in(key, i), name, shape, dtype)
+    for name, leaf in flat.items():
         if "/" in name:
             group, k = name.split("/")
             out.setdefault(group, {})[k] = leaf
@@ -81,16 +59,42 @@ def _make(key, config, dtype):
     return out
 
 
+def _make(key, model, config, dtype):
+    return _nest({name: _draw(jax.random.fold_in(key, i), model.draw(name),
+                              shape, dtype)
+                  for i, (name, shape) in enumerate(_flat(model, config))})
+
+
+def spread(mesh, shape):
+    """A weight's placement over every device of a one-axis ``mesh``:
+    its largest dimension that the devices divide is split among them,
+    and the weight is copied whole where none is."""
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    n = mesh.devices.size
+    dims = [d for d in range(len(shape)) if shape[d] % n == 0]
+    spec = [None] * len(shape)
+    if dims:
+        spec[max(dims, key=lambda d: shape[d])] = mesh.axis_names[0]
+    return NamedSharding(mesh, P(*spec))
+
+
 @functools.lru_cache(maxsize=None)
-def _maker(config_items: tuple, dtype_name: str):
+def _maker(model, config_items: tuple, dtype_name: str, mesh):
     config = dict(config_items)
-    return jax.jit(functools.partial(_make, config=config,
-                                     dtype=jnp.dtype(dtype_name)))
+    out = None
+    if mesh is not None:
+        out = _nest({name: spread(mesh, shape)
+                     for name, shape in _flat(model, config)})
+    return jax.jit(functools.partial(_make, model=model, config=config,
+                                     dtype=jnp.dtype(dtype_name)),
+                   out_shardings=out)
 
 
-def make(config: dict, seed: int, dtype) -> dict:
-    """All weights of ``config`` for ``seed``, as ``dtype``, on the
-    default device."""
+def make(model, config: dict, seed: int, dtype, mesh=None) -> dict:
+    """All weights of ``config`` for ``seed``, as ``dtype``: on the
+    default device, or with a one-axis ``mesh`` spread over its devices
+    (:func:`spread`)."""
     items = tuple(sorted((k, v) for k, v in config.items()
                          if isinstance(v, (int, float, str, bool))))
-    return _maker(items, jnp.dtype(dtype).name)(key_for(seed))
+    return _maker(model, items, jnp.dtype(dtype).name, mesh)(key_for(seed))

@@ -1,6 +1,8 @@
-"""A benchmark root holding fixture files only: a tiny configuration,
-its mixes and cells, added as files and entries the way a later PR adds
-a cell, beside copies of the real readers and references."""
+"""A benchmark root holding fixture files only: tiny configurations,
+their mixes and cells, added as files and entries the way a later PR
+adds a cell, beside copies of the real readers, model modules and
+references.  A fixture cell ``<config>.<mix>`` runs ``tiny-<mix>.json``
+on ``<config>.json``, on as many devices as the mix's mesh holds."""
 
 from __future__ import annotations
 
@@ -23,25 +25,30 @@ def make_root(tmp: Path) -> Path:
     (tmp / "bench" / "configs").mkdir(parents=True)
     (tmp / "bench" / "traffic").mkdir()
     (tmp / "bench" / "cells").mkdir()
-    for d in ("metrics", "reference"):
+    for d in ("metrics", "models", "reference"):
         shutil.copytree(REPO / "bench" / d, tmp / "bench" / d)
-    shutil.copy(FIX / "tiny.json", tmp / "bench" / "configs" / "tiny.json")
-    bench["configs"].append({"name": "tiny", "source": "fixture",
-                             "file": "bench/configs/tiny.json",
-                             "reduced": [], "why": "CPU fixture"})
     cells = json.loads((FIX / "tiny-cells.json").read_text())
+    for conf in sorted({name.split(".")[0] for name in cells}):
+        shutil.copy(FIX / f"{conf}.json",
+                    tmp / "bench" / "configs" / f"{conf}.json")
+        bench["configs"].append({"name": conf, "source": "fixture",
+                                 "file": f"bench/configs/{conf}.json",
+                                 "reduced": [], "why": "CPU fixture"})
     for name, settings in cells.items():
-        mix = name.split(".")[1]
+        conf, mix = name.split(".")
         shutil.copy(FIX / f"tiny-{mix}.json",
                     tmp / "bench" / "traffic" / f"tiny-{mix}.json")
         (tmp / "bench" / "cells" / f"{name}.json").write_text(
             json.dumps(settings))
-        bench["workloads"].append({"name": name, "config": "tiny",
-                                   "traffic": f"tiny-{mix}", "chips": 1,
+        grid = json.loads((FIX / f"tiny-{mix}.json").read_text()).get(
+            "mesh", {"stages": 1, "data": 1})
+        bench["workloads"].append({"name": name, "config": conf,
+                                   "traffic": f"tiny-{mix}",
+                                   "chips": grid["stages"] * grid["data"],
                                    "why": "CPU fixture"})
+        like = {"chat": "qwen3-0.6b.chat", "batch": "qwen3-0.6b.batch",
+                "train": "qwen3-0.6b.train", "pipeline": "qwen3-0.6b.train"}[mix]
         for m in bench["end_to_end"] + bench["per_layer"]:
-            like = {"chat": "qwen3-0.6b.chat", "batch": "qwen3-0.6b.batch",
-                    "train": "qwen3-0.6b.train"}[mix]
             if like in m.get("workloads", [like]) and "workloads" in m:
                 m["workloads"].append(name)
     (tmp / "BENCHMARK.json").write_text(json.dumps(bench, indent=1))

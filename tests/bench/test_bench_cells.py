@@ -43,7 +43,8 @@ def test_fixture_cell_lists_per_layer_metrics(fixture_root):
             "device_idle_share.serve"} <= names
     chat = spec.load_cell("tiny.chat", fixture_root)
     assert {m["name"] for m in chat.per_layer} == {
-        "queue_wait_p90_ms", "decode_step_ms.chat", "device_idle_share.chat"}
+        "queue_wait_p90_ms", "decode_step_ms.chat", "device_idle_share.chat",
+        "admission_stall_p99_ms"}
     train = spec.load_cell("tiny.train", fixture_root)
     assert {m["name"] for m in train.per_layer} == {
         "train_mfu", "device_idle_share.train"}

@@ -1,9 +1,12 @@
 """Operation and byte counts against hand counts for one call."""
 
 from bench import flops, peaks
+from bench.models import qwen3
 
-D = flops.Dims(layers=2, d_model=8, heads=4, kv_heads=2, head_dim=4,
-               d_ff=16, vocab=32, tied=True)
+D = qwen3.dims({"num_hidden_layers": 2, "hidden_size": 8,
+                "num_attention_heads": 4, "num_key_value_heads": 2,
+                "head_dim": 4, "intermediate_size": 16, "vocab_size": 32,
+                "tie_word_embeddings": True})
 
 
 def test_param_counts():
